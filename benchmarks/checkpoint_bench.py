@@ -27,13 +27,14 @@ OUT = os.path.join(REPO, "BENCH_checkpoint.json")
 DEVICES = 8
 
 CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, shutil, tempfile, time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import telemetry
 from repro.checkpoint import restore_checkpoint, save_checkpoint
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 sh = NamedSharding(mesh, P(("pod", "data")))
 rep = NamedSharding(mesh, P())
@@ -60,7 +61,7 @@ for _ in range(ITERS):
 restore_s = (time.perf_counter() - t0) / ITERS
 assert step == ITERS - 1, step
 
-flat = jax.make_mesh((1, 8), ("pod", "data"))
+flat = make_mesh((1, 8), ("pod", "data"))
 fsh = jax.tree.map(
     lambda x: NamedSharding(flat, P(("pod", "data")) if x.ndim == 2
                             else P()), tree)
@@ -95,7 +96,7 @@ def main() -> None:
           ("ckpt_restore", r["restore_wall_s"] * 1e6, "same-layout restore"),
           ("ckpt_reshard", r["reshard_wall_s"] * 1e6,
            "(2,4)->flat(8) reshard restore")])
-    write_bench_json(OUT, {"checkpoint": r}, devices=DEVICES)
+    write_bench_json(OUT, {"checkpoint": r})
 
 
 if __name__ == "__main__":

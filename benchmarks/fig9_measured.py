@@ -15,12 +15,13 @@ from __future__ import annotations
 from .common import emit, run_multidevice
 
 CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, time
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import collectives as C
 
-mesh = jax.make_mesh((4, 4), ("r", "l"))
+mesh = make_mesh((4, 4), ("r", "l"))
 x = jnp.ones((16, 1024), jnp.float32)   # 4 KiB per rank
 def make(alg):
     def body(s):

@@ -98,7 +98,7 @@ def main() -> list[tuple]:
     assert res["decision_latency_s"] is not None, res
     assert res["recoveries"] >= 2 and res["recovery_wall_s"] is not None, res
 
-    write_bench_json(OUT, {"fleet": res}, devices=DEVICES)
+    write_bench_json(OUT, {"fleet": res})
     return emit([
         ("fleet/decision_latency", res["decision_latency_s"] * 1e6,
          f"mean_s={res['decision_latency_s']:.2e} "

@@ -68,6 +68,7 @@ from .common import REPO, emit, run_multidevice, write_bench_json
 OUT = os.path.join(REPO, "BENCH_multipod.json")
 
 TRAIN_HLO_CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, dataclasses
 import jax
 from repro import configs
@@ -75,7 +76,7 @@ from repro.core.hlo_analysis import collective_stats
 from repro.core.topology import device_pod_map
 from repro.train.step import custom_batch_specs, make_train_step
 
-mesh = jax.make_mesh((2, 16), ("pod", "data"))
+mesh = make_mesh((2, 16), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 bspec = custom_batch_specs(cfg, 32, 64)
@@ -145,6 +146,7 @@ print("JSON" + json.dumps(out))
 """
 
 THREEPOD_HLO_CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, dataclasses
 import jax, numpy as np
 from repro import configs
@@ -154,7 +156,7 @@ from repro.serve import ServeSpec
 from repro.serve.engine import cache_specs, make_serve_fns
 from repro.train.step import custom_batch_specs, make_train_step
 
-mesh = jax.make_mesh((3, 8), ("pod", "data"))
+mesh = make_mesh((3, 8), ("pod", "data"))
 jax.set_mesh(mesh)
 # dims divisible by the 3x8 composite span (24) so every FSDP leaf genuinely
 # shards across all three pods — the allgatherv adaptation's domain
@@ -218,6 +220,7 @@ print("JSON" + json.dumps(out))
 """
 
 NUMERICS3_CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -227,7 +230,7 @@ from repro.serve import ServeSpec
 from repro.serve.engine import Engine
 from repro.train.step import custom_batch_specs, init_state, make_train_step
 
-mesh = jax.make_mesh((3, 2), ("pod", "data"))
+mesh = make_mesh((3, 2), ("pod", "data"))
 jax.set_mesh(mesh)
 out = {"mesh": "3x2 (pod,data)", "n_devices": 6}
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2,
@@ -292,6 +295,7 @@ print("JSON" + json.dumps(out))
 """
 
 NUMERICS_CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -300,7 +304,7 @@ from repro.serve import ServeSpec
 from repro.serve.engine import Engine
 from repro.train.step import custom_batch_specs, init_state, make_train_step
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 out = {"mesh": "2x4 (pod,data)", "n_devices": 8}
 
@@ -363,6 +367,7 @@ print("JSON" + json.dumps(out))
 
 
 MIGRATE_HLO_CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -372,7 +377,7 @@ from repro.models import transformer
 from repro.serve import Engine, Request, ServeSpec, StepClock
 from repro.serve.scheduler import make_migrate_insert_fn
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2,
                           dtype=jnp.float32)
@@ -443,6 +448,7 @@ import jax, numpy as np
 from repro import configs
 from repro.core.hlo_analysis import collective_stats, op_payloads
 from repro.core.topology import device_pod_map
+from repro.launch.mesh import make_mesh
 from repro.train.step import custom_batch_specs, make_train_step
 
 out = {}
@@ -451,8 +457,7 @@ base = configs.get_smoke("qwen2-moe-a2.7b")
 # q=3 exercises the non-power partial-round geometry of the inter-pod phase
 for key, (q, pl) in (("moe_2pod", (2, 8)), ("moe_3pod", (3, 8))):
     p = q * pl
-    devs = np.asarray(jax.devices()[:p]).reshape(q, pl)
-    mesh = jax.sharding.Mesh(devs, ("pod", "data"))
+    mesh = make_mesh((q, pl), ("pod", "data"), devices=jax.devices()[:p])
     jax.set_mesh(mesh)
     cfg = dataclasses.replace(base, n_layers=2, n_experts=p)
     bspec = custom_batch_specs(cfg, p, 32)
@@ -581,7 +586,7 @@ def main() -> list[tuple]:
         rows.append((f"multipod/{nkey}/decode", None,
                      f"tokens_exact=True steps={num['decode']['steps']}"))
 
-    write_bench_json(OUT, results, devices=512)
+    write_bench_json(OUT, results)
     return emit(rows)
 
 

@@ -40,6 +40,7 @@ STEPS = 3
 BATCH, SEQ = 8, 64
 
 CODE_TMPL = r"""
+from repro.launch.mesh import make_mesh
 import json, time
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro import configs
@@ -50,7 +51,7 @@ from repro.data import SyntheticLM
 
 ARCH, NL, BATCH, SEQ, STEPS = %r, %d, %d, %d, %d
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke(ARCH), n_layers=NL)
 data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=SEQ,
@@ -150,7 +151,7 @@ def main() -> list[tuple]:
         stdout = run_multidevice(code, devices=8, timeout=1800)
         line = [l for l in stdout.splitlines() if l.startswith("JSON")][0]
         results[name] = json.loads(line[4:])
-    write_bench_json(OUT, results, devices=8)
+    write_bench_json(OUT, results)
 
     rows = []
     for name, r in results.items():

@@ -149,7 +149,7 @@ def main() -> None:
             with telemetry.span("bench/fleet"):
                 fleet_bench.main()
         finally:                   # keep artifacts from failed gate runs
-            telemetry_artifacts("fleet", devices=fleet_bench.DEVICES)
+            telemetry_artifacts("fleet")
         return
     if argv[:1] != ["bench"] and any(a.startswith("--only") for a in argv):
         argv = ["bench"] + argv

@@ -18,6 +18,7 @@ from .common import REPO, emit, run_multidevice, write_bench_json
 OUT = os.path.join(REPO, "BENCH_serve_combine.json")
 
 CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, time
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro import configs
@@ -27,7 +28,7 @@ from repro.serve.spec import ServeSpec
 from repro.core.hlo_analysis import (allreduce_combiners, collective_stats,
                                      op_payloads)
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 B, CL, STEPS = 1, 128, 32
@@ -73,7 +74,7 @@ def main() -> list[tuple]:
     stdout = run_multidevice(CODE, devices=8, timeout=1800)
     line = [l for l in stdout.splitlines() if l.startswith("JSON")][0]
     out = json.loads(line[4:])
-    write_bench_json(OUT, out, devices=8)
+    write_bench_json(OUT, out)
 
     rows = []
     for alg in ("xla", "locality"):

@@ -40,6 +40,7 @@ OUT = os.path.join(REPO, "BENCH_serve_traffic.json")
 DEVICES = 8
 
 CODE = r"""
+from repro.launch.mesh import make_mesh
 import json, time, warnings
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro import configs
@@ -51,7 +52,7 @@ PREFILL_COST = 0.5      # vs 1.0 per decode step
 SLO_FACTOR = 3.0        # SLO = 3x the request's own no-queue latency
 N_REQ = 24
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 params = transformer.init_params(jax.random.PRNGKey(0), cfg)
@@ -192,7 +193,7 @@ def main() -> None:
                      f"{cont['slo_goodput_tokens_per_tick']:.3f} vs "
                      f"{wave['slo_goodput_tokens_per_tick']:.3f} tok/tick"))
     res["gates"] = gates
-    write_bench_json(OUT, res, devices=DEVICES)
+    write_bench_json(OUT, res)
     emit(rows)
 
     for name, g in gates.items():

@@ -30,8 +30,9 @@ def main():
     from repro.core import schedules as S
     from repro.core.autotune import model_costs
     from repro.core.topology import RegionMap
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((args.regions, args.local), ("r", "l"))
+    mesh = make_mesh((args.regions, args.local), ("r", "l"))
     jax.set_mesh(mesh)
     n = int(args.kib * 1024 / 4)
     x = jnp.arange(p * n, dtype=jnp.float32).reshape(p, n)

@@ -16,6 +16,7 @@ import numpy as np
 from repro import configs
 from repro.core.autotune import model_costs
 from repro.data import SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.models import transformer
 from repro.optim import AdamW, TrainState
 from repro.serve import Engine, Request, ServeSpec
@@ -51,7 +52,7 @@ def main():
             print(f"  step {i:3d} loss {float(l):.3f}")
 
     # --- 3. serve -----------------------------------------------------------
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     eng = Engine(cfg, mesh, state.params, ServeSpec(batch=4, cache_len=48))
     prompts = data.batch(999)["tokens"][:4, :16]

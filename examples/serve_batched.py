@@ -27,10 +27,11 @@ def main():
     import warnings
 
     from repro import configs
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer
     from repro.serve import Engine, ServeSpec
 
-    mesh = jax.make_mesh((args.devices // 2, 2), ("data", "model"))
+    mesh = make_mesh((args.devices // 2, 2), ("data", "model"))
     jax.set_mesh(mesh)
     cfg = configs.get_smoke(args.arch)
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)

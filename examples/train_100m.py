@@ -40,6 +40,7 @@ def main():
     import numpy as np
 
     from repro import configs
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer
     from repro.train import Trainer, TrainerConfig
 
@@ -81,7 +82,7 @@ def main():
               f"{len(report.episodes)} episode(s), final layout "
               f"{report.final_layout}")
     else:
-        mesh = jax.make_mesh((2, args.devices // 4, 2),
+        mesh = make_mesh((2, args.devices // 4, 2),
                              ("pod", "data", "model"))
         jax.set_mesh(mesh)
         tr = Trainer(cfg, mesh, tcfg)

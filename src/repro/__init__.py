@@ -1,9 +1,1 @@
-"""repro — locality-aware collectives, training and serving stack.
-
-Importing the package installs JAX version-compat fallbacks (see
-``repro._jax_compat``) so modules written against the current JAX API run
-unchanged on older pinned installs.
-"""
-from . import _jax_compat
-
-_jax_compat.install()
+"""repro — locality-aware collectives, training and serving stack."""

@@ -68,13 +68,25 @@ def _tup(axes: Axes) -> tuple[str, ...]:
     return (axes,) if isinstance(axes, str) else tuple(axes)
 
 
+def _vma_tracked(axes: tuple[str, ...]) -> bool:
+    """Whether the enclosing ``shard_map`` tracks varying manual axes.
+
+    Under ``check_vma=False`` every value reports an empty vma — even
+    ``axis_index``, which is varying by definition under tracking."""
+    return bool(axes) and bool(jax.typeof(lax.axis_index(axes[0])).vma)
+
+
 def _varying(x: jax.Array, axes: tuple[str, ...]) -> jax.Array:
     """Mark ``x`` device-varying over ``axes`` (no-op if already varying).
 
     shard_map's vma tracking treats unvarying inputs as replicated values;
     collectives on them transpose into psums. All algorithms here assume a
-    genuinely per-device shard, so we normalize at entry.
+    genuinely per-device shard, so we normalize at entry. Without tracking
+    (``check_vma=False``) nothing is cast: there the pcast's transpose is a
+    psum that the untracked region rejects, and no cast is needed.
     """
+    if not _vma_tracked(axes):
+        return x
     missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
     return lax.pcast(x, missing, to="varying") if missing else x
 

@@ -114,13 +114,12 @@ def layout_mesh(layout: Layout, devices=None):
     fleet, so consecutive runs of ``pod_size`` share a pod and each mesh
     row stays pod-local by construction)."""
     import jax
-    import numpy as np
+    from repro.launch.mesh import make_mesh
 
     devs = list(jax.devices() if devices is None else devices)
     if layout.total > len(devs):
         raise FleetLayoutError(
             f"layout {layout} needs {layout.total} devices, "
             f"have {len(devs)}")
-    arr = np.array(devs[:layout.total],
-                   dtype=object).reshape(layout.pods, layout.per_pod)
-    return jax.sharding.Mesh(arr, ("pod", "data"))
+    return make_mesh((layout.pods, layout.per_pod), ("pod", "data"),
+                     devices=devs[:layout.total])

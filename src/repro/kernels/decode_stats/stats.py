@@ -13,10 +13,13 @@ Grid: (B·KV, num_kv_blocks), KV axis innermost and sequential
 ("arbitrary"): acc/lsum scratch persist across the KV steps of one row
 group and the outputs are written on the last step.
 
-Masking needs no position logic here: the scores arrive already
-NEG_INF-masked (models/attention.decode_stats_scores), so ``s ≤ NEG_INF/2``
-identifies masked slots — exact for every pattern including the
-fully-masked shard (m = NEG_INF would make exp(s − m) = 1 there).
+Masking needs no position logic for the cache itself: the scores arrive
+already NEG_INF-masked (models/attention.decode_stats_scores), so
+``s ≤ NEG_INF/2`` identifies masked slots — exact for every pattern
+including the fully-masked shard (m = NEG_INF would make exp(s − m) = 1
+there). Only a cache length that is not a multiple of the block needs one
+positional mask: the grid takes ceil(L / block_k) steps and the last block
+reads past L, where both the scores and the values are undefined.
 """
 from __future__ import annotations
 
@@ -27,13 +30,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _pallas_compat
 
 NEG_INF = -2.0 ** 30
 
 
 def _stats_kernel(s_ref, m_ref, v_ref, o_ref, l_ref, acc_scr, lsum_scr, *,
-                  block_k: int):
+                  block_k: int, length: int):
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
 
@@ -44,9 +46,16 @@ def _stats_kernel(s_ref, m_ref, v_ref, o_ref, l_ref, acc_scr, lsum_scr, *,
 
     s = s_ref[0]                                   # (G, block_k) fp32
     m = m_ref[0]                                   # (G, 1) fp32
-    p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m), 0.0)
-    lsum_scr[...] += jnp.sum(p, axis=-1, keepdims=True)
     v = v_ref[0].astype(jnp.float32)               # (block_k, D)
+    live = s > NEG_INF * 0.5
+    if length % block_k:                           # ragged last block
+        base = ki * block_k
+        col = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        row = base + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        live = live & (col < length)
+        v = jnp.where(row < length, v, 0.0)        # 0·NaN would poison o
+    p = jnp.where(live, jnp.exp(s - m), 0.0)
+    lsum_scr[...] += jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[...] += jax.lax.dot(p, v, preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
@@ -66,16 +75,18 @@ def decode_stats_accumulate_pallas(s: jax.Array, m: jax.Array,
     dtype — identical for fp32 caches, tighter for bf16)."""
     B, KV, G, L = s.shape
     D = v_cache.shape[-1]
-    bk = min(block_k, L)
-    if L % bk:
-        bk = L                                     # odd lengths: one block
+    # a cache no longer than one block is one full-extent block; a longer
+    # one takes block_k-sized blocks (a multiple of 128 on the chip: the
+    # score block's lane dim) and masks the ragged tail in-kernel, so VMEM
+    # holds one block whatever L is
+    bk = L if L <= block_k else block_k
     sf = s.reshape(B * KV, G, L)
     mf = m.reshape(B * KV, G, 1)
     vf = v_cache.transpose(0, 2, 1, 3).reshape(B * KV, L, D)
 
-    grid = (B * KV, L // bk)
+    grid = (B * KV, pl.cdiv(L, bk))
     o, l = pl.pallas_call(
-        functools.partial(_stats_kernel, block_k=bk),
+        functools.partial(_stats_kernel, block_k=bk, length=L),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, G, bk), lambda b, j: (b, 0, j)),
@@ -94,7 +105,7 @@ def decode_stats_accumulate_pallas(s: jax.Array, m: jax.Array,
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
-        compiler_params=_pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(sf, mf, vf)

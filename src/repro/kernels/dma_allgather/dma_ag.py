@@ -26,7 +26,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import schedules as S
-from repro.kernels import _pallas_compat
 from .schedule_compile import DmaSchedule, compile_schedule
 
 
@@ -108,13 +107,13 @@ def dma_allgather(x: jax.Array, axes, dma_sched: DmaSchedule, perm: jax.Array,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(
             (cap * n,), x.dtype,
-            vma=frozenset(axes) | getattr(jax.typeof(xf), "vma", frozenset())),
+            vma=frozenset(axes) | jax.typeof(xf).vma),
         scratch_shapes=[pltpu.SemaphoreType.DMA((max(len(dma_sched.sizes), 1),)),
                         pltpu.SemaphoreType.DMA((max(len(dma_sched.sizes), 1),))],
-        compiler_params=_pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=7,  # same logical collective across devices
         ),
-        interpret=(_pallas_compat.interpret_params() if interpret else False),
+        interpret=(pltpu.InterpretParams() if interpret else False),
     )(my_sched, xf)
 
     buf = out.reshape(cap, *x.shape)

@@ -118,9 +118,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
             compiled = lowered.compile()
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            # legacy jax (0.4.x) returns a one-element list of dicts here
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
         pod_map = device_pod_map(mesh, ("pod",)) if multi else None
         stats = collective_stats(hlo, pod_map)

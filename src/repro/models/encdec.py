@@ -79,8 +79,7 @@ def encode(params, cfg, frames, *, shard=None):
         x_c = shard(x_c + mlp_apply(lp["mlp"], h, cfg.mlp_act), "act")
         return x_c, None
 
-    from repro._jax_compat import scan_compat
-    x, _ = scan_compat(body, x, params["enc_layers"])
+    x, _ = jax.lax.scan(body, x, params["enc_layers"])
     return norm_apply(cfg, params["enc_norm"], x)
 
 
@@ -168,8 +167,7 @@ def forward(params, cfg, tokens, *, frames=None, mode="train", cache=None,
 
     body_fn = jax.checkpoint(body) if (remat and mode == "train") else body
     self_stack = cache["self"] if decode else None
-    from repro._jax_compat import scan_compat
-    x, (self_ncs, cross_ncs) = scan_compat(
+    x, (self_ncs, cross_ncs) = jax.lax.scan(
         body_fn, x, (params["dec_layers"], self_stack, cross_stack),
         length=cfg.n_layers)
 

@@ -108,8 +108,12 @@ def ssd_chunked(x, dt, A, B, C, Q: int, h0=None, *, precise: bool = False):
     CBh = (jnp.broadcast_to(CB, (Bt, nc, H, Q, Q)) if G == 1
            else jnp.repeat(CB, Hg, axis=2))
     cum_h = cum.transpose(0, 1, 3, 2)                # (Bt,nc,H,Q)
-    decay = jnp.exp(cum_h[..., :, None] - cum_h[..., None, :])
-    decay = jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), decay, 0.0)
+    # mask before the exponential: above the diagonal the exponent is
+    # positive and overflows f32 at real chunk lengths (Q=128), and exp's
+    # cotangent there would be inf·0 = NaN in every gradient
+    seg = cum_h[..., :, None] - cum_h[..., None, :]
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), seg,
+                              -jnp.inf))
     dtx = dtc.astype(bf16)[..., None] * xc           # (Bt,nc,Q,H,P)
     L = CBh * decay.astype(bf16)
     y_intra = jnp.einsum("bchij,bcjhp->bcihp", L, dtx)
@@ -130,8 +134,7 @@ def ssd_chunked(x, dt, A, B, C, Q: int, h0=None, *, precise: bool = False):
         h = jnp.exp(dec)[..., None, None] * h + s.astype(f32)
         return h, h_out
 
-    from repro._jax_compat import scan_compat
-    h_fin, h_prev = scan_compat(
+    h_fin, h_prev = jax.lax.scan(
         step, h0, (seg_end.transpose(1, 0, 2), S_c.transpose(1, 0, 2, 3, 4)))
     h_prev = h_prev.transpose(1, 0, 2, 3, 4)          # (Bt,nc,H,N,P)
 

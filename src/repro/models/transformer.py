@@ -260,7 +260,6 @@ def forward(params, cfg, tokens, *, img_embeds=None, mode="train", cache=None,
             "prefetch pipeline is train-mode only (see DESIGN.md §5)")
     body_fn = jax.checkpoint(body) if (remat and mode == "train") else body
     scan_cache = cache["blocks"] if decode else None
-    from repro._jax_compat import scan_compat
     if prefetch is not None:
         # Double-buffered pipeline: the scan carries a FIFO of `depth`
         # in-flight gathers. Each iteration issues the gather for layer
@@ -288,12 +287,21 @@ def forward(params, cfg, tokens, *, img_embeds=None, mode="train", cache=None,
         take = lambda i: jax.tree.map(lambda t: t[i], blocks)
         depth = max(1, int(getattr(prefetch, "depth", 1)))
         scan_ncs = None
+
+        def drain(x_c, pendings):
+            # the last layers run as a scan over their stacked weights,
+            # exactly as the eager path runs every layer: straight-line
+            # layers would let XLA fuse across the layer boundary and skip
+            # bf16 roundings the looped layers keep (excess precision)
+            full = [prefetch.finish(p) for p in pendings]
+            stacked = jax.tree.map(lambda *t: jnp.stack(t), *full)
+            x_c, aux_d = jax.lax.scan(block_fn, x_c, stacked)
+            return x_c, jnp.sum(aux_d)
+
         if reps <= depth:
             # lookahead covers the whole stack: issue everything up front
-            pendings = [prefetch.start(take(i)) for i in range(reps)]
-            for i in range(reps):
-                x, aux = block_fn(x, prefetch.finish(pendings[i]))
-                aux_total += aux
+            x, aux = drain(x, [prefetch.start(take(i)) for i in range(reps)])
+            aux_total += aux
         else:
             fifo = tuple(prefetch.start(take(i)) for i in range(depth))
             xs_ahead = jax.tree.map(lambda t: t[depth:], blocks)
@@ -304,14 +312,12 @@ def forward(params, cfg, tokens, *, img_embeds=None, mode="train", cache=None,
                 x_c, aux = block_fn(x_c, prefetch.finish(pend[0]))
                 return (x_c, pend[1:] + (nxt,)), aux
 
-            (x, fifo), aux_s = scan_compat(pf_body, (x, fifo), xs_ahead,
-                                           length=reps - depth)
-            aux_total += jnp.sum(aux_s)
-            for i in range(depth):                      # drain the FIFO
-                x, aux = block_fn(x, prefetch.finish(fifo[i]))
-                aux_total += aux
+            (x, fifo), aux_s = jax.lax.scan(pf_body, (x, fifo), xs_ahead,
+                                            length=reps - depth)
+            x, aux = drain(x, fifo)
+            aux_total += jnp.sum(aux_s) + aux
     else:
-        x, (aux_s, scan_ncs) = scan_compat(
+        x, (aux_s, scan_ncs) = jax.lax.scan(
             body_fn, x, (params["blocks"], scan_cache), length=reps)
         aux_total += jnp.sum(aux_s)
 

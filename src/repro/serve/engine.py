@@ -16,9 +16,9 @@ the sequence-parallel cache reduction:
     the sequence-sharded cache into partial reductions + its own implicit
     combine (an all-reduce of the full per-step stat payload).
   * "locality" — the same forward, but every decode-attention layer runs
-    inside a FULLY-manual ``shard_map`` region (all mesh axes manual — the
-    legacy partitioner cannot place manual-axis collectives in partial-auto
-    regions, see DESIGN.md §3): per-shard flash-style partial stats
+    inside a FULLY-manual ``shard_map`` region (all mesh axes manual, so
+    the cache update and the partial-stat attention stay device-local):
+    per-shard flash-style partial stats
     (o-accumulator, running max, sumexp) from
     ``models.attention.decode_partial_stats``, combined with the explicit
     ``core.collectives.logsumexp_combine``
@@ -321,9 +321,8 @@ def _make_locality_decode_combine(cfg, mesh, seq_cand: tuple[str, ...],
 
     Returns a callable matching ``models.attention.attention``'s
     ``decode_combine`` protocol. Per layer it traces ONE fully-manual
-    ``shard_map`` region (manual over every mesh axis — required on the
-    legacy partitioner, and it keeps the whole cache update + partial-stat
-    attention device-local) that:
+    ``shard_map`` region (manual over every mesh axis — it keeps the whole
+    cache update + partial-stat attention device-local) that:
 
       1. writes the new token's K/V into the owning sequence shard
          (masked device-local dynamic_update_slice — slot ``pos`` lives on
@@ -579,34 +578,29 @@ class Engine:
         if comm_telemetry == "auto":
             comm_telemetry = self.combine.algorithm != "none"
         if comm_telemetry:
-            self._stamp_comm(log)
+            self._stamp_comm()
         if log and self.combine.algorithm != "none":
             log(f"[engine] cache-combine: {self.combine.algorithm} "
                 f"({self.combine.source}, {self.combine.nbytes} B/step, "
                 f"p={self.combine.p} p_local={self.combine.p_local})")
 
-    def _stamp_comm(self, log=None) -> None:
+    def _stamp_comm(self) -> None:
         """AOT-compile the active decode fn; stamp its CommReport."""
         from repro import telemetry
         import time as _time
-        try:
-            with self.tracer.span("serve/compile"):
-                t0 = _time.perf_counter()
-                a_tok = jax.ShapeDtypeStruct((self.batch, 1), jnp.int32)
-                lowered = self.art.decode_fn.lower(
-                    self.art.abstract_params, self.art.abstract_cache, a_tok)
-                compiled = lowered.compile()
-                compile_s = _time.perf_counter() - t0
-            report = telemetry.comm_report(compiled.as_text(), self.mesh,
-                                           label=self.comm_label)
-            self._decode_callable = compiled
-            self.comm_report = report
-            self.registry.gauge("serve/compile_time_s").set(compile_s)
-            self.registry.attach_comm_report(self.comm_label, report)
-        except Exception as e:            # pragma: no cover - backend quirks
-            if log:
-                log(f"[engine] comm telemetry unavailable: "
-                    f"{type(e).__name__}: {e}")
+        with self.tracer.span("serve/compile"):
+            t0 = _time.perf_counter()
+            a_tok = jax.ShapeDtypeStruct((self.batch, 1), jnp.int32)
+            lowered = self.art.decode_fn.lower(
+                self.art.abstract_params, self.art.abstract_cache, a_tok)
+            compiled = lowered.compile()
+            compile_s = _time.perf_counter() - t0
+        report = telemetry.comm_report(compiled.as_text(), self.mesh,
+                                       label=self.comm_label)
+        self._decode_callable = compiled
+        self.comm_report = report
+        self.registry.gauge("serve/compile_time_s").set(compile_s)
+        self.registry.attach_comm_report(self.comm_label, report)
 
     def _next_token(self, logits) -> jax.Array:
         """Greedy sampling rule, shared by prefill and decode so it cannot

@@ -39,9 +39,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import collectives as C
+from repro.launch.mesh import make_mesh
 from repro.models import transformer
 from repro.train.sharding import make_shard_fn, param_specs
 from .paged import PagedKVCache
@@ -254,6 +255,11 @@ class Scheduler:
         self._pod_params: dict[int, Any] = {}
         self._migrations = 0
         self._steps = 0
+        # Diagnostic hook: (B, 1, Vpad) logits of the latest decode step,
+        # row-indexed like the cache (RequestResult.slot). A row holds its
+        # request's final logits only if that request finished on the last
+        # step; chip_smoke.py checks them against a cache-free forward.
+        self.last_logits = None
         self._insert_fn = None
         self._migrate_fn = None
         self._migrate_compiled = None
@@ -293,17 +299,15 @@ class Scheduler:
         self._decode = fn
         self._decode_label = "serve/decode:cont"
         if self.comm_telemetry:
-            try:
-                a_tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+            a_tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+            with self.tracer.span("serve/compile", label=self._decode_label):
                 compiled = fn.lower(art.abstract_params, self.abstract_cache,
                                     a_tok).compile()
-                from repro import telemetry
-                rep = telemetry.comm_report(compiled.as_text(), mesh,
-                                            label=self._decode_label)
-                self.registry.attach_comm_report(self._decode_label, rep)
-                self._decode = compiled
-            except Exception:             # pragma: no cover - backend quirks
-                self.comm_telemetry = False
+            from repro import telemetry
+            rep = telemetry.comm_report(compiled.as_text(), mesh,
+                                        label=self._decode_label)
+            self.registry.attach_comm_report(self._decode_label, rep)
+            self._decode = compiled
         init = jax.jit(lambda: jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), self.abstract_cache),
             out_shardings=self.cache_sh)
@@ -354,26 +358,20 @@ class Scheduler:
         return max(1, total // max(p, 1))
 
     def _stamp_migrate(self) -> None:
-        try:
-            a_cache = jax.tree.map(
-                lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                                   sharding=sh),
-                self.abstract_cache, self.cache_sh)
-            a_req = jax.tree.map(
-                lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                                   sharding=sh),
-                transformer.cache_specs(self.cfg, 1, self.spec.cache_len),
-                self.donor_sh)
-            a_row = jax.ShapeDtypeStruct((), jnp.int32)
-            compiled = self._migrate_fn.lower(a_cache, a_req,
-                                              a_row).compile()
-            from repro import telemetry
-            rep = telemetry.comm_report(compiled.as_text(), self.mesh,
-                                        label=self._migrate_label)
-            self.registry.attach_comm_report(self._migrate_label, rep)
-            self._migrate_compiled = compiled
-        except Exception:                 # pragma: no cover - backend quirks
-            self._migrate_compiled = None
+        a_cache = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            self.abstract_cache, self.cache_sh)
+        a_req = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            transformer.cache_specs(self.cfg, 1, self.spec.cache_len),
+            self.donor_sh)
+        a_row = jax.ShapeDtypeStruct((), jnp.int32)
+        compiled = self._migrate_fn.lower(a_cache, a_req, a_row).compile()
+        from repro import telemetry
+        rep = telemetry.comm_report(compiled.as_text(), self.mesh,
+                                    label=self._migrate_label)
+        self.registry.attach_comm_report(self._migrate_label, rep)
+        self._migrate_compiled = compiled
 
     # -- pod-local prefill ---------------------------------------------
     def _pod_mesh(self, pod: int | None):
@@ -383,9 +381,10 @@ class Scheduler:
         if pod is None:
             return self.mesh
         names = list(self.mesh.axis_names)
-        devs = np.asarray(self.mesh.devices)
-        sub = np.take(devs, pod, axis=names.index("pod"))
-        return Mesh(sub, tuple(n for n in names if n != "pod"))
+        sub = np.take(np.asarray(self.mesh.devices), pod,
+                      axis=names.index("pod"))
+        return make_mesh(sub.shape, tuple(n for n in names if n != "pod"),
+                         devices=sub)
 
     def _prefill_for(self, pod: int | None, S: int):
         """(compiled_prefill, params, tok_sharding, label) for one home pod
@@ -428,21 +427,17 @@ class Scheduler:
                      out_shardings=(None, c_sh))
         label = f"serve/prefill:pod{pod if pod is not None else 'all'}:s{S}"
         if self.comm_telemetry:
-            try:
-                a_tok = jax.ShapeDtypeStruct((1, S), jnp.int32)
-                # trace under the submesh: the forward's bare-P sharding
-                # constraints (model axis) must resolve on the pod's
-                # devices, not the ambient full mesh (Mesh's own context
-                # manager nests and restores, unlike jax.set_mesh)
-                with mesh:
-                    compiled = fn.lower(a_params, a_tok).compile()
-                from repro import telemetry
-                rep = telemetry.comm_report(compiled.as_text(), mesh,
-                                            label=label)
-                self.registry.attach_comm_report(label, rep)
-                fn = compiled
-            except Exception:             # pragma: no cover
-                pass
+            a_tok = jax.ShapeDtypeStruct((1, S), jnp.int32)
+            # trace under the submesh: the forward's bare-P sharding
+            # constraints (model axis) must resolve on the pod's devices,
+            # not the ambient full mesh
+            with jax.set_mesh(mesh), self.tracer.span("serve/compile",
+                                                       label=label):
+                compiled = fn.lower(a_params, a_tok).compile()
+            from repro import telemetry
+            rep = telemetry.comm_report(compiled.as_text(), mesh, label=label)
+            self.registry.attach_comm_report(label, rep)
+            fn = compiled
         entry = (fn, params, tok_sh, label, mesh)
         self._prefills[key] = entry
         return entry
@@ -495,6 +490,7 @@ class Scheduler:
             logits, self._cache = self._decode(self.engine.params,
                                                self._cache, toks)
             nxt = np.asarray(self._next_token(logits))
+        self.last_logits = logits
         self.clock.advance("decode")
         self._steps += 1
         if self.comm_telemetry:
@@ -691,9 +687,11 @@ class Scheduler:
         fn, params, tok_sh, label, mesh_sub = self._prefill_for(pod, S)
         toks = jax.device_put(jnp.asarray(req.tokens)[None, :], tok_sh)
         with self.tracer.span("serve/prefill", rid=req.rid, prompt_len=S):
-            with mesh_sub:                  # non-AOT path traces here
+            # the prefill (non-AOT path: its trace) and the sampling of its
+            # logits, which live on the pod's devices, run under the submesh
+            with jax.set_mesh(mesh_sub):
                 logits, req_cache = fn(params, toks)
-            tok0 = np.asarray(self._next_token(logits))
+                tok0 = np.asarray(self._next_token(logits))
         self.clock.advance("prefill")
         if self.comm_telemetry \
                 and self.registry.comm_report(label) is not None:
@@ -757,6 +755,7 @@ class Scheduler:
             logits, self._cache = self._decode(self.engine.params,
                                                self._cache, tok)
             nxt = np.asarray(self._next_token(logits))
+        self.last_logits = logits
         self.clock.advance("decode")
         self._steps += 1
         if self.engine.comm_report is not None:

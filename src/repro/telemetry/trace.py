@@ -9,10 +9,10 @@ and https://ui.perfetto.dev load directly.
 Nesting is tracked with a ``contextvars`` stack, so spans opened in
 ``async``/generator code attribute to the right parent, and each OS thread
 gets its own lane (``tid``) — the checkpoint writer thread's spans land in
-their own track. When the running JAX exposes
-``jax.profiler.TraceAnnotation`` (≥0.4.x), every span also enters a profiler
-annotation of the same name, so an XLA/Perfetto device profile carries the
-paper's phase names next to the HLO ops they bracket.
+their own track. Every span also enters a ``jax.profiler.TraceAnnotation``
+of the same name (unless ``jax_annotations=False``), so an XLA/Perfetto
+device profile carries the paper's phase names next to the HLO ops they
+bracket.
 
 ``validate_trace_events`` is the schema half the tests and
 ``scripts/check_metrics_schema.py`` share: per-thread monotonic ``ts`` and
@@ -27,17 +27,10 @@ import os
 import threading
 import time
 
+import jax.profiler
+
 _SPAN_STACK: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "repro_span_stack", default=())
-
-
-def _jax_annotation(name: str):
-    """Best-effort jax.profiler annotation for a span (None when absent)."""
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return None
 
 
 class Tracer:
@@ -75,20 +68,12 @@ class Tracer:
         if stack:
             ev.setdefault("args", {})["parent"] = stack[-1]
         self._emit(ev)
-        ann = _jax_annotation(name) if self.jax_annotations else None
-        if ann is not None:
-            try:
-                ann.__enter__()
-            except Exception:
-                ann = None
+        ann = (jax.profiler.TraceAnnotation(name) if self.jax_annotations
+               else contextlib.nullcontext())
         try:
-            yield self
+            with ann:
+                yield self
         finally:
-            if ann is not None:
-                try:
-                    ann.__exit__(None, None, None)
-                except Exception:
-                    pass
             self._emit({"ph": "E", "name": name, "cat": "repro",
                         "ts": self._now_us(), "pid": self._pid, "tid": tid})
             _SPAN_STACK.reset(token)

@@ -258,13 +258,6 @@ def make_shard_fn(mesh=None, *, manual_dp: bool = False, seq_shard: bool = False
     seq_shard: sequence-parallel residuals — shard the seq dim of (B,S,d)
     activations over 'model' between blocks (perf knob).
     """
-    from repro import _jax_compat
-    if manual_dp and _jax_compat.LEGACY_PARTIAL_AUTO:
-        # Legacy JAX: any wsc inside a partially-manual shard_map body trips
-        # the old SPMD partitioner ("Incompatible manual sharding"). Dropping
-        # the hints is safe — XLA replicates the auto ('model') axis within
-        # the manual region instead of tiling it.
-        enable = False
     if not enable:
         return lambda x, kind: x
     dp = dp_axes(mesh) if mesh is not None else DP_AXES

@@ -258,7 +258,7 @@ def make_train_step(cfg, mesh, *, optimizer: AdamW | None = None,
     the measured per-dispatch overhead of the live backend — a host-CPU
     harness with no real wire resolves to 0. Applies to paper-mode FSDP
     on the transformer family; degrades to eager where the in-scan gather
-    cannot run (legacy partial-auto split, encdec).
+    cannot run (encdec).
 
     moe_dispatch: locality expert parallelism (DESIGN.md §12) — "none" keeps
     replicated experts; "locality" / "xla" shard routed-expert weights over
@@ -321,7 +321,6 @@ def make_train_step(cfg, mesh, *, optimizer: AdamW | None = None,
         dp_size *= mesh.devices.shape[list(mesh.axis_names).index(ax)]
 
     # --- locality expert-parallel dispatch resolution (DESIGN.md §12) -------
-    from repro import _jax_compat
     from repro.models.moe import MoeDispatch
     _names = list(mesh.axis_names)
     n_pods = mesh.devices.shape[_names.index("pod")] if "pod" in _names else 1
@@ -331,9 +330,7 @@ def make_train_step(cfg, mesh, *, optimizer: AdamW | None = None,
     ep_ok = (moe_dispatch != "none" and grad_sync != "xla"
              and cfg.family != "audio" and getattr(cfg, "n_experts", 0) > 0
              and dp_size > 1 and cfg.n_experts % dp_size == 0
-             and int(b_abstract["tokens"].shape[0]) % dp_size == 0
-             and not (_jax_compat.LEGACY_PARTIAL_AUTO
-                      and set(mesh.axis_names) - set(dp)))
+             and int(b_abstract["tokens"].shape[0]) % dp_size == 0)
     if not ep_ok:
         moe_algorithm = "none"
         moe_dispatch_source = "n/a"
@@ -449,8 +446,7 @@ def make_train_step(cfg, mesh, *, optimizer: AdamW | None = None,
         def sbody(acc, mb):
             return jax.tree.map(lambda a, b: a + b, acc, one_fn(mb)), None
 
-        from repro._jax_compat import scan_compat
-        acc, _ = scan_compat(sbody, init, mbs)
+        acc, _ = jax.lax.scan(sbody, init, mbs)
         return jax.tree.map(lambda t: t / grad_accum, acc)
 
     # --- gradient computation ---------------------------------------------
@@ -595,91 +591,12 @@ def make_train_step(cfg, mesh, *, optimizer: AdamW | None = None,
                 lambda t: jax.lax.psum(t, dp) / dp_size, metrics)
             return grads, metrics
 
-        from repro import _jax_compat
-        non_dp = set(mesh.axis_names) - set(dp)
-        if _jax_compat.LEGACY_PARTIAL_AUTO and non_dp:
-            # Legacy XLA cannot partition manual-axis collectives
-            # (ppermute/axis_index/psum) inside a *partially* manual
-            # computation — it RET_CHECKs on the manual-subgroup shardings.
-            # Split paper mode into two regions: fwd/bwd in the partial-auto
-            # shard_map (no collectives; per-shard grads leave stacked on a
-            # fresh leading dp axis), then the locality-aware sync in a
-            # FULLY manual shard_map over every mesh axis, where the
-            # ppermute schedules partition fine. One extra device-local
-            # reshape per leaf; identical numerics and collective schedule.
-            # FSDP degrades to ZeRO-1 semantics here: the in-body Bruck
-            # param gather is also a manual-axis collective, so GSPMD
-            # gathers at the jit boundary instead (in_specs P() below) and
-            # the step's final with_sharding_constraint re-scatters. The
-            # prefetch pipeline needs the in-body gather, so it degrades
-            # with it (reflected in StepArtifacts.prefetch_depth = 0).
-            if resolved_depth:
-                _warn(f"prefetch_depth={resolved_depth} requested but the "
-                      f"legacy partial-auto split cannot run the in-scan "
-                      f"gather: degrading to eager",
-                      requested_depth=resolved_depth, legacy=True)
-            resolved_depth, prefetch_source = 0, "n/a"
-            nogather_dims = jax.tree.map(lambda _: -1, fsdp_dims)
-
-            def _strip_dp(sp: P) -> P:
-                # drop every DP axis ('data' AND 'pod' of the composite
-                # FSDP entries) — the sync shard_map re-stacks the grads on
-                # a fresh leading dp axis, so an inner pod/data entry would
-                # name a manual axis twice.
-                ent = []
-                for s in sp:
-                    names = (s,) if isinstance(s, str) else tuple(s or ())
-                    names = tuple(n for n in names if n not in dp)
-                    ent.append(names[0] if len(names) == 1
-                               else (names or None))
-                return P(*ent)
-
-            sync_pspecs = jax.tree.map(_strip_dp, pspecs,
-                                       is_leaf=lambda x: isinstance(x, P))
-
-            def compute_body(params, batch):
-                shard = make_shard_fn(mesh, manual_dp=True, seq_shard=seq_shard)
-
-                def one(mb):
-                    def sharded_loss(shards):
-                        full = jax.tree.map(_gather, shards, nogather_dims)
-                        return loss_fn(full, mb, shard)
-                    return jax.value_and_grad(sharded_loss, has_aux=True)(params)
-
-                (_, metrics), grads = _accumulated(one, batch)
-                stack = lambda t: t[None]
-                return jax.tree.map(stack, grads), jax.tree.map(stack, metrics)
-
-            def sync_body(grads, metrics):
-                grads = jax.tree.map(lambda t: t[0], grads)
-                leaves, treedef = jax.tree.flatten(grads)
-                leaves = bucketed_sync(leaves, sync_full,
-                                       bucket_mb=bucket_mb, compress=compress)
-                grads = jax.tree.unflatten(treedef, leaves)
-                metrics = jax.tree.map(
-                    lambda t: jax.lax.psum(t[0], dp) / dp_size, metrics)
-                return grads, metrics
-
-            compute = jax.shard_map(
-                compute_body, mesh=mesh,
-                in_specs=(P(), {k: b_specs[k] for k in b_abstract}),
-                out_specs=(P(dp), P(dp)),
-                axis_names=set(dp), check_vma=False)
-            sync_in = jax.tree.map(lambda sp: P(dp, *tuple(sp)), sync_pspecs,
-                                   is_leaf=lambda x: isinstance(x, P))
-            sync = jax.shard_map(
-                sync_body, mesh=mesh, in_specs=(sync_in, P(dp)),
-                out_specs=(sync_pspecs, P()), check_vma=False)
-
-            def grads_of(params, batch):
-                return sync(*compute(params, batch))
-        else:
-            in_specs = (param_in_specs if (fsdp or ep_on) else P(),
-                        {k: b_specs[k] for k in b_abstract})
-            out_specs = ((param_in_specs if (fsdp or ep_on) else P()), P())
-            grads_of = jax.shard_map(
-                body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                axis_names=set(dp), check_vma=False)
+        in_specs = (param_in_specs if (fsdp or ep_on) else P(),
+                    {k: b_specs[k] for k in b_abstract})
+        out_specs = ((param_in_specs if (fsdp or ep_on) else P()), P())
+        grads_of = jax.shard_map(
+            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            axis_names=set(dp), check_vma=False)
 
     # --- the full step -------------------------------------------------------
     def step(state: TrainState, batch):

@@ -48,7 +48,7 @@ class TrainerConfig:
     steps: int = 100
     seq_len: int = 128
     global_batch: int = 8
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str | None = None       # required: where this run checkpoints
     ckpt_every: int = 25
     keep_last: int = 3
     log_every: int = 10
@@ -61,8 +61,7 @@ class TrainerConfig:
     seed: int = 0
     straggler_k: float = 3.0
     # AOT-compile the step and stamp its CommReport (HLO comm ground truth)
-    # into the metrics registry; falls back to the plain jitted step (with a
-    # "warning" event) if the AOT path is unavailable on this backend.
+    # into the metrics registry; a compile failure raises.
     comm_telemetry: bool = True
 
 
@@ -86,6 +85,10 @@ class Trainer:
         self.status = "initialized"
         self._ckpt_failures_seen = 0
         self.monitor = StepMonitor(k=tcfg.straggler_k)
+        if not tcfg.ckpt_dir:
+            # a shared default directory would let an unrelated earlier run's
+            # LATEST be resumed by resume="auto"
+            raise ValueError("TrainerConfig.ckpt_dir is required")
         self.ckpt = CheckpointManager(tcfg.ckpt_dir, keep_last=tcfg.keep_last)
         self.events: list[TelemetryEvent] = []
         self.log = log
@@ -167,29 +170,24 @@ class Trainer:
         self.comm_label = f"train/step:{self.artifacts.grad_sync}"
         if not t.comm_telemetry:
             return
-        try:
-            with self.tracer.span("train/compile"):
-                t0 = time.perf_counter()
-                lowered = self.artifacts.step_fn.lower(
-                    self.artifacts.abstract_state, self._abstract_batch())
-                compiled = lowered.compile()
-                compile_s = time.perf_counter() - t0
-            hlo = compiled.as_text()
-            report = telemetry.comm_report(hlo, self.mesh,
-                                           label=self.comm_label)
-            self._step_callable = compiled
-            self.comm_report = report
-            self.registry.gauge("train/compile_time_s").set(compile_s)
-            self.registry.attach_comm_report(self.comm_label, report)
-            self._event(
-                f"comm report: {report.nonlocal_bytes:.0f} inter-pod B / "
-                f"{report.nonlocal_msgs:.0f} msgs, {report.dp_bytes:.0f} "
-                f"DP-crossing B per step "
-                f"(locality_schedule={report.has_locality_schedule})",
-                kind="comm", attrs=report.asdict(), log=False)
-        except Exception as e:            # pragma: no cover - backend quirks
-            self._event(f"comm telemetry unavailable: "
-                        f"{type(e).__name__}: {e}", kind="warning")
+        with self.tracer.span("train/compile"):
+            t0 = time.perf_counter()
+            lowered = self.artifacts.step_fn.lower(
+                self.artifacts.abstract_state, self._abstract_batch())
+            compiled = lowered.compile()
+            compile_s = time.perf_counter() - t0
+        hlo = compiled.as_text()
+        report = telemetry.comm_report(hlo, self.mesh, label=self.comm_label)
+        self._step_callable = compiled
+        self.comm_report = report
+        self.registry.gauge("train/compile_time_s").set(compile_s)
+        self.registry.attach_comm_report(self.comm_label, report)
+        self._event(
+            f"comm report: {report.nonlocal_bytes:.0f} inter-pod B / "
+            f"{report.nonlocal_msgs:.0f} msgs, {report.dp_bytes:.0f} "
+            f"DP-crossing B per step "
+            f"(locality_schedule={report.has_locality_schedule})",
+            kind="comm", attrs=report.asdict(), log=False)
 
     def _stamp_moe_comm(self, t: TrainerConfig) -> None:
         """Per-layer attribution ledger for the locality MoE dispatch: lower
@@ -204,67 +202,63 @@ class Trainer:
         art = self.artifacts
         if not t.comm_telemetry or art.moe_dispatch == "none":
             return
-        try:
-            import jax.numpy as jnp
-            from jax.sharding import PartitionSpec as P
-            from repro.models import moe as moe_mod
-            from repro.models.moe import MoeDispatch
-            from repro.train.sharding import dp_axes
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+        from repro.models import moe as moe_mod
+        from repro.models.moe import MoeDispatch
+        from repro.train.sharding import dp_axes
 
-            cfg = self.model_cfg
-            mesh = self.mesh
-            dp = dp_axes(mesh)
-            outer = ("pod",) if "pod" in mesh.axis_names else ()
-            local = tuple(a for a in dp if a != "pod")
-            names = list(mesh.axis_names)
-            p = 1
-            for ax in dp:
-                p *= np.asarray(mesh.devices).shape[names.index(ax)]
-            hook = MoeDispatch(outer=outer, local=local,
-                               algorithm=art.moe_dispatch,
-                               transport=art.moe_transport, p=p)
-            E, d = cfg.n_experts, cfg.d_model
-            dff = cfg.d_expert or cfg.d_ff
-            S = t.seq_len
-            C_cap = moe_mod.capacity(cfg, S)
-            dt = jnp.dtype(cfg.dtype)
-            pdt = jnp.dtype(cfg.param_dtype)
+        cfg = self.model_cfg
+        mesh = self.mesh
+        dp = dp_axes(mesh)
+        outer = ("pod",) if "pod" in mesh.axis_names else ()
+        local = tuple(a for a in dp if a != "pod")
+        names = list(mesh.axis_names)
+        p = 1
+        for ax in dp:
+            p *= np.asarray(mesh.devices).shape[names.index(ax)]
+        hook = MoeDispatch(outer=outer, local=local,
+                           algorithm=art.moe_dispatch,
+                           transport=art.moe_transport, p=p)
+        E, d = cfg.n_experts, cfg.d_model
+        dff = cfg.d_expert or cfg.d_ff
+        S = t.seq_len
+        C_cap = moe_mod.capacity(cfg, S)
+        dt = jnp.dtype(cfg.dtype)
+        pdt = jnp.dtype(cfg.param_dtype)
 
-            def body(params, x_pad, tok_idx):
-                return moe_mod._ep_apply(params, x_pad, tok_idx, cfg, hook,
-                                         C_cap)
+        def body(params, x_pad, tok_idx):
+            return moe_mod._ep_apply(params, x_pad, tok_idx, cfg, hook,
+                                     C_cap)
 
-            fn = jax.shard_map(
-                body, mesh=mesh,
-                in_specs=({k: P(dp, None, None)
-                           for k in ("gate", "up", "down")},
-                          P(dp, None, None), P(dp, None)),
-                out_specs=P(dp, None, None),
-                axis_names=set(dp), check_vma=False)
-            B = t.global_batch
-            a_params = {
-                "gate": jax.ShapeDtypeStruct((E, d, dff), pdt),
-                "up": jax.ShapeDtypeStruct((E, d, dff), pdt),
-                "down": jax.ShapeDtypeStruct((E, dff, d), pdt),
-            }
-            a_x = jax.ShapeDtypeStruct((B, S + 1, d), dt)
-            a_idx = jax.ShapeDtypeStruct((B, E * C_cap), jnp.int32)
-            hlo = jax.jit(fn).lower(a_params, a_x, a_idx).compile().as_text()
-            label = f"train/moe_dispatch:{art.moe_dispatch}"
-            report = telemetry.comm_report(hlo, mesh, label=label)
-            self.registry.attach_comm_report(label, report)
-            self.moe_comm_label = label
-            self._moe_layers = sum(1 for s in cfg.layer_plan()
-                                   if s.mlp == "moe")
-            self._event(
-                f"moe dispatch comm ({art.moe_dispatch}/"
-                f"{art.moe_transport}): {report.nonlocal_bytes:.0f} "
-                f"inter-pod B / {report.nonlocal_msgs:.0f} msgs per layer "
-                f"x {self._moe_layers} layers/step",
-                kind="comm", attrs=report.asdict(), log=False)
-        except Exception as e:            # pragma: no cover - backend quirks
-            self._event(f"moe dispatch telemetry unavailable: "
-                        f"{type(e).__name__}: {e}", kind="warning")
+        fn = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=({k: P(dp, None, None)
+                       for k in ("gate", "up", "down")},
+                      P(dp, None, None), P(dp, None)),
+            out_specs=P(dp, None, None),
+            axis_names=set(dp), check_vma=False)
+        B = t.global_batch
+        a_params = {
+            "gate": jax.ShapeDtypeStruct((E, d, dff), pdt),
+            "up": jax.ShapeDtypeStruct((E, d, dff), pdt),
+            "down": jax.ShapeDtypeStruct((E, dff, d), pdt),
+        }
+        a_x = jax.ShapeDtypeStruct((B, S + 1, d), dt)
+        a_idx = jax.ShapeDtypeStruct((B, E * C_cap), jnp.int32)
+        hlo = jax.jit(fn).lower(a_params, a_x, a_idx).compile().as_text()
+        label = f"train/moe_dispatch:{art.moe_dispatch}"
+        report = telemetry.comm_report(hlo, mesh, label=label)
+        self.registry.attach_comm_report(label, report)
+        self.moe_comm_label = label
+        self._moe_layers = sum(1 for s in cfg.layer_plan()
+                               if s.mlp == "moe")
+        self._event(
+            f"moe dispatch comm ({art.moe_dispatch}/"
+            f"{art.moe_transport}): {report.nonlocal_bytes:.0f} "
+            f"inter-pod B / {report.nonlocal_msgs:.0f} msgs per layer "
+            f"x {self._moe_layers} layers/step",
+            kind="comm", attrs=report.asdict(), log=False)
 
     def _init_or_restore(self, step: int | None = None) -> None:
         with self.tracer.span("train/restore"):

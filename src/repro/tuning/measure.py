@@ -305,13 +305,13 @@ def _measure_real(collective: str, algorithm: str, p: int, p_local: int,
     from jax.sharding import PartitionSpec as P
 
     from repro.core import collectives as C
+    from repro.launch.mesh import make_mesh
 
     devs = jax.devices()
     if len(devs) < p:
         raise RuntimeError(f"need {p} devices, have {len(devs)}")
-    r = p // p_local
-    mesh_devs = np.asarray(devs[:p]).reshape(r, p_local)
-    mesh = jax.sharding.Mesh(mesh_devs, ("outer", "local"))
+    mesh = make_mesh((p // p_local, p_local), ("outer", "local"),
+                     devices=devs[:p])
     itemsize = jnp.dtype(dtype).itemsize
     n_elems = max(1, int(nbytes) // itemsize)
     x = jnp.zeros((p * n_elems,), dtype=dtype)

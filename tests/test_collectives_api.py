@@ -103,6 +103,7 @@ def test_deprecated_alias_warns_once(name):
 # ---------------------------------------------------------------------------
 
 API_ROUNDTRIP_CODE = r"""
+from repro.launch.mesh import make_mesh
 import warnings
 import repro  # noqa: F401
 import jax, jax.numpy as jnp
@@ -110,7 +111,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 import repro.core.collectives as C
 
-mesh = jax.make_mesh((2, 2), ("pod", "data"))
+mesh = make_mesh((2, 2), ("pod", "data"))
 x = jnp.arange(4 * 3, dtype=jnp.float32).reshape(4, 3)
 run = lambda f, a=x: jax.jit(jax.shard_map(f, mesh=mesh,
     in_specs=P(("pod", "data")), out_specs=P(("pod", "data"))))(a)

@@ -9,12 +9,13 @@ import pytest
 pytestmark = pytest.mark.slow      # multi-device subprocess suite
 
 COLLECTIVES_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import collectives as C
 
-mesh = jax.make_mesh((4, 4), ("pod", "local"))
+mesh = make_mesh((4, 4), ("pod", "local"))
 x = jnp.arange(16 * 3, dtype=jnp.float32).reshape(16, 3)
 def run(fn, arr=None):
     arr = x if arr is None else arr
@@ -124,13 +125,14 @@ print("NONPOWER_OK")
 
 
 GRAD_SYNC_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import numpy as np, dataclasses
 from repro import configs
 from repro.train.step import make_train_step, init_state, custom_batch_specs
 from repro.data import SyntheticLM
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8, seed=0)
@@ -154,12 +156,13 @@ print("GRAD_SYNC_OK", losses["xla"])
 """
 
 DMA_KERNEL_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.kernels.dma_allgather.ops import dma_locality_allgather
 
-mesh = jax.make_mesh((2, 4), ("r", "l"))
+mesh = make_mesh((2, 4), ("r", "l"))
 jax.set_mesh(mesh)
 x = jnp.arange(8 * 6, dtype=jnp.float32).reshape(8, 6)
 def run(fn):
@@ -175,13 +178,14 @@ print("DMA_OK")
 """
 
 SEQ_SHARD_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import numpy as np, dataclasses
 from repro import configs
 from repro.train.step import make_train_step, init_state, custom_batch_specs
 from repro.data import SyntheticLM
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8, seed=0)
@@ -216,18 +220,6 @@ def test_grad_sync_modes_agree(subproc):
     assert "GRAD_SYNC_OK" in subproc(GRAD_SYNC_CODE, devices=8)
 
 
-def _legacy_pallas_interpret() -> bool:
-    from repro.kernels import _pallas_compat
-    return _pallas_compat._InterpretParams is None
-
-
-@pytest.mark.xfail(
-    _legacy_pallas_interpret(),
-    reason="pallas interpret-mode DMA discharge on this JAX version rejects "
-           "meshes with more than one named dimension (dma_start_p "
-           "NotImplementedError) — the cross-device DMA interpreter only "
-           "exists in the newer TPU interpret backend",
-    strict=False)
 def test_dma_allgather_kernel(subproc):
     assert "DMA_OK" in subproc(DMA_KERNEL_CODE, devices=8)
 

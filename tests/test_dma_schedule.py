@@ -1,7 +1,7 @@
 """DMA schedule compilation: table executor oracle + paper properties."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import schedules as S
 from repro.core.topology import RegionMap, ceil_log
@@ -29,7 +29,7 @@ def test_tables_correct(pl, k):
 @given(st.sampled_from([2, 4, 8, 16]), st.sampled_from([1, 2, 3]))
 def test_raw_locality_preserves_paper_traffic(pl, k):
     """The DMA-clean variant must not inflate non-local traffic vs Alg. 2."""
-    from _hypothesis_compat import assume
+    from hypothesis import assume
     assume(pl ** (k + 1) <= 1024)        # tables are O(p²) host memory
     r = pl ** k
     p = r * pl

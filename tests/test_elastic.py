@@ -36,6 +36,7 @@ pytestmark = pytest.mark.slow
 # leg 1 (8 devices): baseline + kill/resume across layouts + preemption
 # ---------------------------------------------------------------------------
 BITWISE_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, os
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -63,7 +64,7 @@ def hexes(ls):
     return " ".join(float(l).hex() for l in ls)
 
 def mesh(shape):
-    m = jax.make_mesh(shape, ("pod", "data"))
+    m = make_mesh(shape, ("pod", "data"))
     jax.set_mesh(m)
     return m
 
@@ -141,6 +142,7 @@ print("PREEMPT_BITWISE_OK")
 # leg 2 (12 devices): reshard the step-4 checkpoint onto q=3 pods + FSDP
 # ---------------------------------------------------------------------------
 RESHARD_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, hashlib, os
 import jax, jax.numpy as jnp, numpy as np
 import jax.tree_util as jtu
@@ -151,7 +153,7 @@ CKDIR = os.environ["ELASTIC_CKDIR"]
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2,
                           d_model=96, d_ff=192, vocab_size=384,
                           dtype=jnp.float32)
-mesh = jax.make_mesh((3, 4), ("pod", "data"))
+mesh = make_mesh((3, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 tcfg = TrainerConfig(steps=8, seq_len=32, global_batch=24, ckpt_every=100,
                      keep_last=4, log_every=100, grad_sync="locality",
@@ -182,6 +184,7 @@ print("RESHARD12_OK")
 # leg 3 (8 devices): serve graceful drain -> fresh-engine resume
 # ---------------------------------------------------------------------------
 SERVE_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, os
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -189,7 +192,7 @@ from repro.checkpoint import read_manifest
 from repro.serve import Engine, Request, ServeSpec, StepClock
 
 CKDIR = os.environ["ELASTIC_CKDIR"]
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2,
                           dtype=jnp.float32)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.checkpoint import (FAULT_POINTS, committed_step,
                               restore_checkpoint, save_checkpoint)
 from repro.faults import (FaultHarness, FaultSpec, ProcessKilled, guard,

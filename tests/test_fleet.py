@@ -15,7 +15,7 @@ import signal
 
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.fleet import (ACTION_COUNTERS, ChaosSchedule, ChaosSpec,
                          FleetPolicy, FleetSignals, Layout, PolicyConfig,
                          choose_layout, layout_price_s, pod_aligned_layouts)

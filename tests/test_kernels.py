@@ -102,7 +102,8 @@ DECODE_STATS_CASES = [
 
 
 @pytest.mark.parametrize("case", DECODE_STATS_CASES)
-@pytest.mark.parametrize("dims", [(1, 8, 4, 64, 128), (2, 6, 2, 32, 96)])
+@pytest.mark.parametrize("dims", [(1, 8, 4, 64, 128), (2, 6, 2, 32, 96),
+                                  (1, 4, 4, 64, 100)])   # ragged last block
 def test_decode_stats_kernel(case, dims):
     from repro.models.attention import decode_stats_scores, decode_partial_stats
     B, H, KV, D, L = dims
@@ -162,6 +163,27 @@ def test_ssd_kernel(dims):
     scale = float(jnp.max(jnp.abs(y2))) + 1e-6
     assert float(jnp.max(jnp.abs(y1 - y2))) / scale < 1e-5
     assert float(jnp.max(jnp.abs(h1 - h2))) < 1e-4
+
+
+def test_ssd_chunked_grads_finite_at_real_chunk():
+    """zamba2's published chunk (Q=128) with the init's decay range (A down
+    to -16, dt up to 0.1): the masked upper triangle must not overflow into
+    NaN gradients."""
+    from repro.models.ssm import ssd_chunked
+    Bt, S, H, P, G, N = 1, 128, 2, 8, 1, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(ks[0], (Bt, S, H, P))
+    dt = jnp.full((Bt, S, H), 0.1)
+    A = -jnp.array([16.0, 1.0])
+    B = jax.random.normal(ks[2], (Bt, S, G, N)) * 0.5
+    C = jax.random.normal(ks[3], (Bt, S, G, N)) * 0.5
+
+    def loss(x, dt, B, C):
+        y, h = ssd_chunked(x, dt, A, B, C, 128)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + jnp.sum(h ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))(x, dt, B, C)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
 
 
 def test_ssd_chunk_invariance():

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro import configs
 from repro.models.moe import _dispatch_tables, capacity, moe_apply, moe_init
@@ -144,8 +144,9 @@ MOE_LEAVES = ("router", "gate", "up", "down", "shared")
 """
 
 EP_BITWISE_Q2_CODE = _EP_PRELUDE + r"""
+from repro.launch.mesh import make_mesh
 base = configs.get_smoke("qwen2-moe-a2.7b")
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 
 # single MoE layer: tokens-vs-slots loss + router/expert grads bitwise
@@ -177,9 +178,10 @@ print("EP_BITWISE_Q2_OK")
 """
 
 EP_BITWISE_Q3_CODE = _EP_PRELUDE + r"""
+from repro.launch.mesh import make_mesh
 base = configs.get_smoke("qwen2-moe-a2.7b")
 # q=3 exercises the non-power partial-round geometry; E=6 divides p=6
-mesh3 = jax.make_mesh((3, 2), ("pod", "data"), devices=jax.devices()[:6])
+mesh3 = make_mesh((3, 2), ("pod", "data"), devices=jax.devices()[:6])
 jax.set_mesh(mesh3)
 cfg3 = dataclasses.replace(base, n_layers=1, n_experts=6)
 r3 = {md: run(cfg3, mesh3, md, gb=6, fsdp=True)
@@ -202,6 +204,7 @@ print("EP_BITWISE_Q3_OK")
 """
 
 A2A_HLO_CODE = r"""
+from repro.launch.mesh import make_mesh
 import repro  # noqa: F401
 import jax, jax.numpy as jnp
 import numpy as np
@@ -212,7 +215,7 @@ from repro.core.topology import device_pod_map
 
 q, pl = {q}, {pl}
 p = q * pl
-mesh = jax.make_mesh((q, pl), ("pod", "data"))
+mesh = make_mesh((q, pl), ("pod", "data"))
 pod_map = device_pod_map(mesh, ("pod",))
 x = jnp.arange(p * p * 3, dtype=jnp.float32).reshape(p * p, 3)
 
@@ -246,6 +249,7 @@ print("A2A_HLO_OK")
 """
 
 EP_LEDGER_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, tempfile
 import repro  # noqa: F401
 import jax
@@ -253,7 +257,7 @@ from repro import configs, telemetry
 from repro.train.trainer import Trainer, TrainerConfig
 
 cfg = dataclasses.replace(configs.get_smoke("qwen2-moe-a2.7b"), n_layers=2)
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 reg = telemetry.MetricsRegistry()
 t = TrainerConfig(steps=3, seq_len=32, global_batch=8,

@@ -286,6 +286,7 @@ def test_bench_trend_plot_history(tmp_path, monkeypatch):
 # integration: layouts agree (8-device subprocess)
 # ---------------------------------------------------------------------------
 EQUIV_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -294,7 +295,7 @@ from repro.serve.engine import Engine
 from repro.serve.spec import ServeSpec
 from repro.train.step import custom_batch_specs, init_state, make_train_step
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8,
@@ -339,6 +340,7 @@ def test_multipod_layouts_agree(subproc):
 
 
 EQUIV3_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -347,7 +349,7 @@ from repro.serve.engine import Engine
 from repro.serve.spec import ServeSpec
 from repro.train.step import custom_batch_specs, init_state, make_train_step
 
-mesh = jax.make_mesh((3, 2), ("pod", "data"))
+mesh = make_mesh((3, 2), ("pod", "data"))
 jax.set_mesh(mesh)
 # dims divisible by the 3x2 composite span so the FSDP transpose really runs
 # Algorithm 2's allgatherv rounds (the wrapped final round is PARTIAL here)

@@ -8,8 +8,7 @@ Guarantee layers:
     layouts (exact ``np.array_equal``, no tolerance);
   * pipeline exactness: eager (prefetch_depth=0) and prefetched (1, 2)
     train steps produce bitwise-identical losses and updated params on
-    dense and windowed-ring plans; TP-mixed legacy meshes degrade to eager
-    and stay exact;
+    dense and windowed-ring plans, and on a TP-mixed mesh;
   * issue order: in the lowered (trace-order) module, the prefetched
     variant shows the next gather's collective-permutes BEFORE the previous
     layer's consumer dot — the dataflow freedom XLA's latency-hiding
@@ -19,9 +18,10 @@ Guarantee layers:
 """
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 SPLIT_BIT_IDENTICAL_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -91,7 +91,7 @@ for shape, names in CASES:
     assert np.allclose(np.asarray(t_eager), np.asarray(truth)), shape
 
 # single-axis degenerate split (the FSDP gather over 'data' only)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 x = jnp.arange(8 * 5, dtype=jnp.float32).reshape(8, 5)
 def run1(fn):
     f = jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
@@ -160,12 +160,13 @@ def test_split_transpose_property(subproc, layout, rows, cols):
 
 
 ISSUE_ORDER_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import re
 from jax.sharding import PartitionSpec as P
 from repro.core import collectives as C
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 p = 8
 
 def prefetched(s0, s1, x):
@@ -213,6 +214,7 @@ def test_prefetched_gather_issued_before_consumer(subproc):
 
 
 TRAIN_EXACT_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import numpy as np, dataclasses
 from repro import configs
@@ -231,7 +233,7 @@ def one_step(cfg, mesh, depth):
     state2, metrics = art.step_fn(state, batch)
     return art, float(metrics["loss"]), state2
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 for arch in ("llama3.2-3b", "gemma2-9b"):       # dense + windowed-ring plan
     cfg = dataclasses.replace(configs.get_smoke(arch), n_layers=4)
@@ -247,22 +249,20 @@ for arch in ("llama3.2-3b", "gemma2-9b"):       # dense + windowed-ring plan
         assert all(np.array_equal(np.asarray(a), np.asarray(b))
                    for a, b in zip(pa, pb)), (arch, d)
 
-# TP-mixed: on legacy partial-auto meshes the pipeline degrades to eager
-# (StepArtifacts reports it) and stays exact
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+# TP-mixed: the pipeline runs inside the partially-manual region (DP axes
+# manual, 'model' auto) and stays exact
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 losses = {}
 for depth in (0, 1):
     art, loss, _ = one_step(cfg, mesh, depth)
     losses[depth] = loss
-    from repro import _jax_compat
-    if _jax_compat.LEGACY_PARTIAL_AUTO:
-        assert art.prefetch_depth == 0, art
+    assert art.prefetch_depth == depth, art
 assert losses[0] == losses[1], losses
 
 # "auto" resolves through the tuning policy's overlap term
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8,
@@ -287,6 +287,7 @@ def test_train_prefetch_exact(subproc):
 
 
 SERVE_FUSED_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -294,7 +295,7 @@ from repro.models import transformer
 from repro.serve.engine import make_serve_fns
 from repro.serve.spec import ServeSpec
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2,
                           dtype=jnp.float32)

@@ -2,7 +2,7 @@
 import math
 
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import schedules as S
 from repro.core.topology import RegionMap, ceil_log
@@ -41,7 +41,7 @@ def test_paper_eq3_bruck_counts(case):
 def test_paper_eq4_locality_counts(pl, k):
     """Locality-aware Bruck with r = p_ℓ^k regions: ceil(log_pl(r)) non-local
     messages per rank; non-local blocks = sum_i pl^(i+1) (paper §4)."""
-    from _hypothesis_compat import assume
+    from hypothesis import assume
     assume(pl ** (k + 1) <= 1024)        # generators are O(p²) host memory
     r = pl ** k
     p = r * pl

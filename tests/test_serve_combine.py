@@ -22,6 +22,7 @@ import pytest
 B_SEQ = 1          # sequence-parallel layouts decode a single long row
 
 EXACT_MATCH_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, json
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
@@ -71,7 +72,7 @@ def check_arch(arch, mesh8, mesh1, n_layers=2):
     assert rec["invocations"] == NEW and rec["match"], rec
     return t_ref
 
-mesh8 = jax.make_mesh((8,), ("data",))
+mesh8 = make_mesh((8,), ("data",))
 mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
 
 check_arch("llama3.2-3b", mesh8, mesh1)     # dense, full attention
@@ -79,7 +80,7 @@ check_arch("gemma2-9b", mesh8, mesh1)       # [window, full] plan: ring cache
 check_arch("whisper-tiny", mesh8, mesh1)    # encoder-decoder self-attn cache
 
 # mixed sequence x tensor parallelism: KV heads sharded over 'model'
-mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+mesh42 = make_mesh((4, 2), ("data", "model"))
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2,
                           dtype=jnp.float32)
 params = transformer.init_params(jax.random.PRNGKey(0), cfg)
@@ -103,6 +104,7 @@ def test_locality_decode_exact_match(subproc):
 
 
 HLO_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, json, math
 import jax, jax.numpy as jnp
 from repro import configs
@@ -112,7 +114,7 @@ from repro.serve.spec import ServeSpec
 from repro.core.hlo_analysis import (allreduce_combiners, collective_stats,
                                      op_payloads)
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 B, CL, n = 1, 64, 8
@@ -131,7 +133,10 @@ for name, fn in (("locality", art.decode_fn_locality),
                  "ar_payloads": op_payloads(hlo, "all-reduce")}
 
 loc, xla = out["locality"], out["xla"]
-layers, lg = cfg.n_layers, int(math.log2(n))
+# the scanned layers share one while body in the HLO text: count the
+# layers it spells out (one period of the plan plus the remainder)
+period, _, rem = transformer.find_period(cfg.layer_plan())
+layers, lg = period + rem, int(math.log2(n))
 # 1. the explicit schedule: one packed-sum reduce-scatter per attention
 #    layer, plus max-phase recursive doubling and the Bruck allgather
 assert loc["counts"].get("reduce-scatter", 0) >= layers, loc
@@ -171,12 +176,13 @@ def test_locality_decode_hlo_has_no_stat_allreduce(subproc):
 
 
 COMBINE_PRIMITIVES_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import collectives as C
 
-mesh = jax.make_mesh((2, 4), ("pod", "local"))
+mesh = make_mesh((2, 4), ("pod", "local"))
 x = jnp.arange(8 * 5, dtype=jnp.float32).reshape(8, 5) * 0.7 - 11.0
 
 def run(fn, arr, out_specs=None):
@@ -226,14 +232,15 @@ def test_logsumexp_combine_primitives(subproc):
 
 
 RESOLVE_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, json
 import jax, numpy as np
 from repro import configs
 from repro.serve.engine import resolve_cache_combine
 
 cfg = configs.get_smoke("llama3.2-3b")
-mesh_d = jax.make_mesh((8,), ("data",))
-mesh_m = jax.make_mesh((8,), ("model",))
+mesh_d = make_mesh((8,), ("data",))
+mesh_m = make_mesh((8,), ("model",))
 out = {
     "batch_sharded": resolve_cache_combine(cfg, mesh_d, batch=8, cache_len=64),
     "seq_sharded": resolve_cache_combine(cfg, mesh_d, batch=1, cache_len=64),
@@ -306,11 +313,12 @@ def test_engine_stats_and_next_token_single_device():
     import jax.numpy as jnp
     import numpy as np
     from repro import configs
+    from repro.launch.mesh import make_mesh
     from repro.serve.engine import Engine
     from repro.serve.spec import ServeSpec
 
     cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     from repro.models import transformer
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)

@@ -21,8 +21,9 @@ import warnings
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, strategies as st
+from hypothesis import given, strategies as st
 
+from repro.launch.mesh import make_mesh
 from repro.serve.paged import PagedKVCache
 from repro.serve.spec import Request, ServeSpec
 
@@ -113,7 +114,7 @@ def test_spec_resolve_single_device():
     import jax
     from repro import configs
     cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=1)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     res = ServeSpec(batch=2, cache_len=16).resolve(cfg, mesh)
     assert res.n_pods == 1 and res.p_local == 1
     assert res.combine.algorithm == "none"           # nothing to combine
@@ -130,7 +131,7 @@ def tiny():
     from repro.models import transformer
     cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=1,
                               dtype=jnp.float32)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     return cfg, mesh, params
 
@@ -183,13 +184,14 @@ def test_generate_deprecated_but_intact(tiny):
 # determinism + parity + locality on the real 8-device batch path
 # ---------------------------------------------------------------------------
 TRACE_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, warnings
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
 from repro.models import transformer
 from repro.serve import Engine, Request, ServeSpec, StepClock
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2,
                           dtype=jnp.float32)

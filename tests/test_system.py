@@ -11,6 +11,7 @@ import numpy as np
 
 from repro import configs
 from repro.models import transformer
+from repro.launch.mesh import make_mesh
 from repro.serve import Engine, ServeSpec
 
 
@@ -48,7 +49,7 @@ def test_end_to_end_tiny_train_then_serve(tmp_path):
     assert losses[-1] < losses[0] - 1.0, (losses[0], losses[-1])
 
     # serve greedily; verify continuation follows tokens[t+1] = a*t + c
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     eng = Engine(cfg, mesh, state.params, ServeSpec(batch=4, cache_len=48))
     b = data.batch(1000)

@@ -344,6 +344,7 @@ def test_check_metrics_schema_fails_on_bad_trace(tmp_path):
 # ---------------------------------------------------------------------------
 
 E2E_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, json, os, shutil
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs, telemetry
@@ -357,7 +358,7 @@ telemetry.set_registry(telemetry.MetricsRegistry())
 tracer = telemetry.get_tracer()
 registry = telemetry.get_registry()
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 jax.set_mesh(mesh)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
 shutil.rmtree("/tmp/repro_ckpt_telemetry", ignore_errors=True)

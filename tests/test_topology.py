@@ -1,5 +1,5 @@
 import pytest
-from _hypothesis_compat import given, strategies as st
+from hypothesis import given, strategies as st
 from conftest import fake_mesh as _fake_mesh
 
 pytestmark = pytest.mark.hypothesis

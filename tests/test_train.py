@@ -7,12 +7,13 @@ from repro.runtime import StepMonitor
 pytestmark = pytest.mark.slow      # multi-device subprocess suite
 
 TRAINER_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, shutil, dataclasses
 from repro import configs
 from repro.train import Trainer, TrainerConfig
 from repro.runtime import FaultInjector
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 jax.set_mesh(mesh)
 shutil.rmtree("/tmp/repro_ckpt_pytest", ignore_errors=True)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
@@ -38,13 +39,14 @@ print("TRAINER_OK", first, last)
 """
 
 ELASTIC_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, shutil, dataclasses
 from repro import configs
 from repro.train import Trainer, TrainerConfig
 
 shutil.rmtree("/tmp/repro_ckpt_elastic", ignore_errors=True)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
-mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh8 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 jax.set_mesh(mesh8)
 tcfg = TrainerConfig(steps=10, seq_len=32, global_batch=8, ckpt_every=10,
                      ckpt_dir="/tmp/repro_ckpt_elastic", log_every=100,
@@ -54,7 +56,7 @@ tr.run()
 l8 = tr.metrics_history[-1]["loss"]
 
 # elastic restart on a SMALLER mesh (lost a pod: 8 -> 4 chips)
-mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+mesh4 = make_mesh((2, 2), ("data", "model"))
 jax.set_mesh(mesh4)
 tr2 = Trainer(cfg, mesh4, dataclasses.replace(tcfg, steps=14),
               log=lambda s: None)

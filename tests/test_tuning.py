@@ -248,19 +248,21 @@ def test_monitor_logs_algorithm_changes():
 def test_serve_combine_resolution_single_device():
     import jax
     from repro import configs
+    from repro.launch.mesh import make_mesh
     from repro.serve.engine import resolve_cache_combine
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = configs.get_smoke("llama3.2-3b")
     choice = resolve_cache_combine(cfg, mesh, batch=4, cache_len=64)
     assert choice.algorithm == "none"       # no sequence sharding on 1 chip
 
 
 GRAD_SYNC_AUTO_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, dataclasses, shutil
 from repro import configs
 from repro.train import Trainer, TrainerConfig
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 jax.set_mesh(mesh)
 shutil.rmtree("/tmp/repro_ckpt_auto", ignore_errors=True)
 cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
@@ -287,6 +289,7 @@ def test_trainer_grad_sync_auto(subproc):
 
 
 AUTO_EQUIV_CODE = r"""
+from repro.launch.mesh import make_mesh
 import os, tempfile
 import jax, jax.numpy as jnp
 import numpy as np
@@ -303,7 +306,7 @@ os.environ["REPRO_TUNING_TABLE"] = path
 set_default_policy(None)                      # rediscover from env
 
 pol = default_policy()
-mesh = jax.make_mesh((4, 4), ("pod", "local"))
+mesh = make_mesh((4, 4), ("pod", "local"))
 for n in (3, 16384):
     sel = pol.select("allgather", 16, 4, n * 4)
     assert sel.source == "table", sel
