@@ -473,29 +473,41 @@ class Scheduler:
         return False
 
     def step(self) -> list[RequestResult]:
-        """Admit what fits, run one decode step, harvest finished rows."""
-        self._admit()
-        if not self.active:
-            if self.queue:
-                self.clock.idle_until(self.queue[0].arrival_s)
-                self._admit()
+        """Admit what fits, run one decode step, harvest finished rows.
+
+        The whole step is one ``serve/step`` span, so every host moment of
+        it is charged somewhere: its children are ``serve/idle`` (napping
+        toward the next arrival), each admission's ``serve/prefill`` and
+        ``serve/insert``, and ``serve/decode_step``; the rest is the
+        scheduler's own work (admission, the token upload, the harvest)."""
+        with self.tracer.span("serve/step"):
+            self._admit()
             if not self.active:
-                return []
-        if self.sequential:
-            return self._step_sequential()
-        toks = jnp.asarray(self._tok)
-        if self.comm_telemetry:
-            toks = jax.device_put(toks, self.engine.art.tok_sharding)
-        with self.tracer.span("serve/decode_step"):
-            logits, self._cache = self._decode(self.engine.params,
-                                               self._cache, toks)
-            nxt = np.asarray(self._next_token(logits))
-        self.last_logits = logits
-        self.clock.advance("decode")
-        self._steps += 1
-        if self.comm_telemetry:
-            self.registry.record_comm(self._decode_label)
-        return self._harvest(nxt)
+                if self.queue:
+                    with self.tracer.span("serve/idle"):
+                        self.clock.idle_until(self.queue[0].arrival_s)
+                    self._admit()
+                if not self.active:
+                    return []
+            if self.sequential:
+                return self._step_sequential()
+            toks = jnp.asarray(self._tok)
+            if self.comm_telemetry:
+                toks = jax.device_put(toks, self.engine.art.tok_sharding)
+            with self.tracer.span("serve/decode_step"):
+                # launch: returns once the step is enqueued; fetch: the host
+                # waits for the device, then copies the sampled tokens
+                with self.tracer.span("serve/decode_launch"):
+                    logits, self._cache = self._decode(self.engine.params,
+                                                       self._cache, toks)
+                with self.tracer.span("serve/decode_fetch"):
+                    nxt = np.asarray(self._next_token(logits))
+            self.last_logits = logits
+            self.clock.advance("decode")
+            self._steps += 1
+            if self.comm_telemetry:
+                self.registry.record_comm(self._decode_label)
+            return self._harvest(nxt)
 
     def drain(self) -> dict[int, RequestResult]:
         """Run until queue and batch are empty; all results by rid."""
@@ -722,9 +734,10 @@ class Scheduler:
                         self._migrate_label) is not None:
                     self.registry.record_comm(self._migrate_label)
             else:
-                req_cache = jax.device_put(req_cache, self.rep_sh)
-                self._cache = self._insert_fn(self._cache, req_cache,
-                                              jnp.asarray(row, jnp.int32))
+                with self.tracer.span("serve/insert", rid=req.rid, row=row):
+                    req_cache = jax.device_put(req_cache, self.rep_sh)
+                    self._cache = self._insert_fn(
+                        self._cache, req_cache, jnp.asarray(row, jnp.int32))
         t = self.clock.now()
         st = _Active(req=req, row=row, started_s=t, migrated=migrated)
         st.tokens.append(int(tok0[0, 0]))
@@ -752,9 +765,11 @@ class Scheduler:
         if self.engine.comm_report is not None:
             tok = jax.device_put(tok, self.engine.art.tok_sharding)
         with self.tracer.span("serve/decode_step"):
-            logits, self._cache = self._decode(self.engine.params,
-                                               self._cache, tok)
-            nxt = np.asarray(self._next_token(logits))
+            with self.tracer.span("serve/decode_launch"):
+                logits, self._cache = self._decode(self.engine.params,
+                                                   self._cache, tok)
+            with self.tracer.span("serve/decode_fetch"):
+                nxt = np.asarray(self._next_token(logits))
         self.last_logits = logits
         self.clock.advance("decode")
         self._steps += 1
@@ -770,7 +785,6 @@ class Scheduler:
     def _finish(self, rid: int, reason: str) -> RequestResult:
         st = self.active.pop(rid)
         self.paged.release(rid)
-        self.registry.count("serve/tokens", len(st.tokens))
         return self._finish_meta(rid, st.req, st, reason)
 
     def _finish_meta(self, rid: int, req: Request, st, reason: str

@@ -23,6 +23,7 @@ The contract under test (DESIGN.md §10):
   on both the batch-sharded and the sequence-sharded (locality-combine)
   layouts.
 """
+import json
 import os
 import re
 
@@ -185,7 +186,7 @@ print("RESHARD12_OK")
 # ---------------------------------------------------------------------------
 SERVE_CODE = r"""
 from repro.launch.mesh import make_mesh
-import dataclasses, os
+import dataclasses, json, os
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
 from repro.checkpoint import read_manifest
@@ -246,9 +247,13 @@ def submit_two(eng):
     b = eng.submit(Request(tokens=p1, max_new=4, arrival_s=0.0))
     return a, b
 
-eng0 = Engine(cfg1, mesh, params1, spec1, clock=StepClock())
+from repro import telemetry
+tracer = telemetry.Tracer(jax_annotations=False)
+eng0 = Engine(cfg1, mesh, params1, spec1, clock=StepClock(), tracer=tracer)
 r0, r1 = submit_two(eng0)
 ref = eng0.drain()
+print("SEQ_SPANS", json.dumps({"events": tracer.events(),
+                                "rids": sorted(ref)}))
 
 ckdir = CKDIR + "/serve_seq"
 eng1 = Engine(cfg1, mesh, params1, spec1, clock=StepClock())
@@ -312,7 +317,7 @@ def test_kill_resume_reshard_bitwise(subproc, tmp_path):
     np.testing.assert_allclose(rloss, base[4:], rtol=5e-3, atol=1e-3)
 
 
-def test_serve_drain_checkpoint_resume(subproc, tmp_path):
+def test_serve_drain_checkpoint_resume(subproc, tmp_path, serve_spans):
     """Engine.drain(checkpoint_dir=...) + fresh-engine resume replays
     every unfinished request to the uninterrupted engine's exact tokens
     (batch-sharded and sequence-sharded layouts)."""
@@ -320,3 +325,9 @@ def test_serve_drain_checkpoint_resume(subproc, tmp_path):
     out = subproc(SERVE_CODE, devices=8, timeout=1800)
     assert "SERVE_BATCH_RESUME_OK" in out, out
     assert "SERVE_SEQ_RESUME_OK" in out, out
+    # the sequential path's span tree (its step clock is virtual, so the
+    # stamps are not on the tracer's clock)
+    (line,) = re.findall(r"^SEQ_SPANS (.*)$", out, re.M)
+    seq = json.loads(line)
+    assert len(seq["rids"]) == 2
+    serve_spans(seq["events"], dict.fromkeys(seq["rids"]), batched=False)

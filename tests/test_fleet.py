@@ -353,10 +353,12 @@ def test_chaos_schedule_rejects_overfull_draw():
 def test_controller_converges_and_counters_reconcile(tmp_path):
     import dataclasses
 
+    import jax
     import jax.numpy as jnp
 
     from repro import configs
     from repro.fleet import FleetController
+    from repro.launch.mesh import make_mesh
     from repro.train import Trainer, TrainerConfig
 
     cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=1,
@@ -381,7 +383,10 @@ def test_controller_converges_and_counters_reconcile(tmp_path):
                                         first_step=3, delay_s=0.05))
         fc = FleetController(make_trainer, pod_size=1, devices=1,
                              chaos=chaos, log=lambda s: None, registry=reg)
-        report = fc.run()
+        # the controller sets the global mesh of each trainer it builds;
+        # the context restores the one before, so it leaks into no later test
+        with jax.set_mesh(make_mesh((1,), ("data",))):
+            report = fc.run()
     finally:
         set_registry(old)
 

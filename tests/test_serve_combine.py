@@ -319,21 +319,21 @@ def test_engine_stats_and_next_token_single_device():
 
     cfg = dataclasses.replace(configs.get_smoke("llama3.2-3b"), n_layers=2)
     mesh = make_mesh((1,), ("data",))
-    jax.set_mesh(mesh)
-    from repro.models import transformer
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-    eng = Engine(cfg, mesh, params, ServeSpec(batch=2, cache_len=32))
-    assert eng.combine.algorithm == "none"
-    prompts = np.zeros((2, 4), np.int32)
-    toks = eng.generate(prompts, 3)
-    assert toks.shape == (2, 3)
-    st = eng.stats()
-    assert st["decode_steps"] == 3
-    assert st["combine_steps"] == 0 and st["combine_bytes"] == 0
-    assert "comm" not in st          # combine "none": telemetry stays off
-    assert eng.comm_report is None
-    # the sampling rule is the one helper: clamps padded-vocab ids
-    big = jnp.zeros((2, 1, cfg.padded_vocab))
-    big = big.at[:, :, cfg.padded_vocab - 1].set(9.0)
-    tok = eng._next_token(big)
-    assert int(tok.max()) <= cfg.vocab_size - 1
+    with jax.set_mesh(mesh):   # scoped: a global mesh leaks to later tests
+        from repro.models import transformer
+        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+        eng = Engine(cfg, mesh, params, ServeSpec(batch=2, cache_len=32))
+        assert eng.combine.algorithm == "none"
+        prompts = np.zeros((2, 4), np.int32)
+        toks = eng.generate(prompts, 3)
+        assert toks.shape == (2, 3)
+        st = eng.stats()
+        assert st["decode_steps"] == 3
+        assert st["combine_steps"] == 0 and st["combine_bytes"] == 0
+        assert "comm" not in st          # combine "none": telemetry stays off
+        assert eng.comm_report is None
+        # the sampling rule is the one helper: clamps padded-vocab ids
+        big = jnp.zeros((2, 1, cfg.padded_vocab))
+        big = big.at[:, :, cfg.padded_vocab - 1].set(9.0)
+        tok = eng._next_token(big)
+        assert int(tok.max()) <= cfg.vocab_size - 1
