@@ -221,6 +221,37 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=0, chunk=0, cap=0.0,
     return (o / l[..., None]).astype(v_cache.dtype)
 
 
+def write_token(cache, new, pos, layer=None, *, ring=False):
+    """Write one decode token's keys or values ``new`` (B,1,KV,D) into
+    ``cache`` at slot ``pos`` (``pos % L`` for a ring) and return it.
+
+    ``cache`` is one layer's (B,L,KV,D), or with ``layer`` given the layer
+    scan's stacked (reps,B,L,KV,D) leaf, written at entry ``layer`` only.
+    ``pos`` is a scalar (lockstep) or per-row (B,) (continuous batching).
+    """
+    L = cache.shape[-3]
+    slot = pos % L if ring else pos
+    new = new.astype(cache.dtype)
+    lead = () if layer is None else (layer,)
+    if jnp.ndim(pos) == 0:
+        if layer is not None:
+            new = new[None]
+        return jax.lax.dynamic_update_slice(cache, new, lead + (0, slot, 0, 0))
+    # row b's token at slot[b]: the batch axis is a batching dim of the
+    # scatter, so a batch-sharded cache is written where it lives and the
+    # stacked leaf keeps its own layout
+    b = len(lead)
+    idx = jnp.stack([jnp.broadcast_to(i, slot.shape) for i in lead]
+                    + [jnp.clip(slot, 0, L - 1)], axis=-1)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2), inserted_window_dims=(*range(b), b + 1),
+        scatter_dims_to_operand_dims=(*range(b), b + 1),
+        operand_batching_dims=(b,), scatter_indices_batching_dims=(0,))
+    return jax.lax.scatter(cache, idx, new[:, 0], dnums,
+                           indices_are_sorted=True, unique_indices=True,
+                           mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
 # ---------------------------------------------------------------------------
 # public layer apply
 # ---------------------------------------------------------------------------
@@ -232,7 +263,7 @@ def attention(params, x, cfg, spec, *, positions=None, cache=None,
     Modes:
       cache None, cross_kv None : full-sequence self-attention; returns
                                   (out, (k, v)) so prefill can build a cache.
-      cache (k,v,pos)           : single-token decode; returns (out, new_cache).
+      cache (k,v,pos[,layer])   : single-token decode; returns (out, new_cache).
       cross_kv (k,v)            : cross-attention (whisper decoder); no mask.
 
     decode_combine: optional serve-layer hook replacing the decode cache
@@ -271,33 +302,35 @@ def attention(params, x, cfg, spec, *, positions=None, cache=None,
 
     if cache is not None:
         # decode: write this token's KV (ring slot pos % L for ring caches)
-        # then attend to the cache.
+        # then attend to the cache. With cache["layer"] set, k/v are the
+        # layer scan's stacked (reps,B,L,KV,D) leaves and this layer reads
+        # and writes entry ``layer`` of them in place.
         k_cache, v_cache, pos = cache["k"], cache["v"], cache["pos"]
-        L_c = k_cache.shape[1]
+        layer = cache.get("layer")
         ring = bool(cache.get("ring", False))
+        read = ((lambda c: c) if layer is None else
+                (lambda c: jax.lax.dynamic_index_in_dim(c, layer, 0, False)))
         res = None
         if decode_combine is not None:
-            res = decode_combine(q, k, v, k_cache, v_cache, pos,
+            res = decode_combine(q, k, v, read(k_cache), read(v_cache), pos,
                                  {"window": window, "chunk": chunk,
                                   "cap": cfg.attn_softcap, "ring": ring})
         if res is None:
-            slot = pos % L_c if ring else pos
-            if jnp.ndim(pos) == 1:
-                # continuous batching: per-row write positions (B,)
-                row_dus = jax.vmap(
-                    lambda c, u, s: jax.lax.dynamic_update_slice(
-                        c, u, (s, 0, 0)))
-                k_cache = row_dus(k_cache, k.astype(k_cache.dtype), slot)
-                v_cache = row_dus(v_cache, v.astype(v_cache.dtype), slot)
-            else:
-                k_cache = jax.lax.dynamic_update_slice(
-                    k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(
-                    v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
-            o = decode_attention(q, k_cache, v_cache, pos, window=window,
-                                 chunk=chunk, cap=cfg.attn_softcap, ring=ring)
+            k_cache = write_token(k_cache, k, pos, layer, ring=ring)
+            v_cache = write_token(v_cache, v, pos, layer, ring=ring)
+            o = decode_attention(q, read(k_cache), read(v_cache), pos,
+                                 window=window, chunk=chunk,
+                                 cap=cfg.attn_softcap, ring=ring)
         else:
-            o, k_cache, v_cache = res
+            # the hook returns the whole updated layer slice
+            o, k_layer, v_layer = res
+            if layer is None:
+                k_cache, v_cache = k_layer, v_layer
+            else:
+                k_cache = jax.lax.dynamic_update_index_in_dim(
+                    k_cache, k_layer, layer, 0)
+                v_cache = jax.lax.dynamic_update_index_in_dim(
+                    v_cache, v_layer, layer, 0)
         new_cache = {"k": k_cache, "v": v_cache, "pos": pos}
         out = o.reshape(B, S, H * D) @ params["wo"].astype(dt)
         return out, new_cache

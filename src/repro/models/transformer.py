@@ -93,12 +93,24 @@ def _shared_attn_init(rng, cfg) -> dict:
 
 def _apply_layer(lp, x, cfg, spec, *, positions, cache, build_cache,
                  cache_len, pos, shard: Shard, decode_combine=None,
-                 moe_dispatch=None):
-    """Returns (x, aux, new_cache)."""
+                 moe_dispatch=None, layer=None):
+    """Returns (x, aux, new_cache).
+
+    ``layer`` set (decode in the layer scan): ``cache`` holds the slot's
+    stacked (reps, ...) leaves and the returned cache is them with entry
+    ``layer`` updated in place.
+    """
     aux = jnp.zeros((), jnp.float32)
     if spec.mixer == "mamba2":
         h = norm_apply(cfg, lp["ln"], x)
-        if cache is not None:
+        if cache is not None and layer is not None:
+            c = {k: jax.lax.dynamic_index_in_dim(t, layer, 0, False)
+                 for k, t in cache.items()}
+            y, nc = mamba_apply(lp["mamba"], h, cfg, cache=c, shard=shard)
+            nc = {k: jax.lax.dynamic_update_index_in_dim(cache[k], nc[k],
+                                                         layer, 0)
+                  for k in cache}
+        elif cache is not None:
             y, nc = mamba_apply(lp["mamba"], h, cfg, cache=cache, shard=shard)
         elif build_cache:
             y, nc = mamba_apply(lp["mamba"], h, cfg, cache={}, shard=shard)
@@ -110,7 +122,7 @@ def _apply_layer(lp, x, cfg, spec, *, positions, cache, build_cache,
     h = norm_apply(cfg, lp["ln1"], x)
     if cache is not None:
         attn_cache = {"k": cache["k"], "v": cache["v"], "pos": pos,
-                      "ring": ring_len is not None}
+                      "ring": ring_len is not None, "layer": layer}
         a, nc_full = attention(lp["attn"], h, cfg, spec, positions=positions,
                                cache=attn_cache, shard=shard,
                                decode_combine=decode_combine)
@@ -235,22 +247,37 @@ def forward(params, cfg, tokens, *, img_embeds=None, mode="train", cache=None,
 
     aux_total = jnp.zeros((), jnp.float32)
 
-    def body(x_carry, xs):
-        lp_all, cache_all = xs
+    def body(x_carry, lp_all):
         aux_acc = jnp.zeros((), jnp.float32)
         ncs = {}
         for j, spec in enumerate(block_specs):
             lp = (params["shared_attn"] if spec.mixer == "shared_attn"
                   else lp_all[f"slot{j}"])
-            c = cache_all[f"slot{j}"] if cache_all is not None else None
             x_carry, aux, nc = _apply_layer(
-                lp, x_carry, cfg, spec, positions=positions, cache=c,
+                lp, x_carry, cfg, spec, positions=positions, cache=None,
                 build_cache=build_cache, cache_len=cache_len, pos=pos,
-                shard=shard, decode_combine=decode_combine,
-                moe_dispatch=moe_dispatch if mode == "train" else None)
+                shard=shard, moe_dispatch=moe_dispatch if mode == "train"
+                else None)
             aux_acc += aux
             ncs[f"slot{j}"] = nc
         return x_carry, (aux_acc, ncs)
+
+    def decode_body(carry, lp_all):
+        # the stacked cache rides in the carry: layer i reads its entries
+        # and writes them back in place (as xs/ys, XLA copies whole stacks
+        # to alias the donated input)
+        x_carry, blocks, i = carry
+        blocks = dict(blocks)
+        aux_acc = jnp.zeros((), jnp.float32)
+        for j, spec in enumerate(block_specs):
+            lp = (params["shared_attn"] if spec.mixer == "shared_attn"
+                  else lp_all[f"slot{j}"])
+            x_carry, aux, blocks[f"slot{j}"] = _apply_layer(
+                lp, x_carry, cfg, spec, positions=positions,
+                cache=blocks[f"slot{j}"], build_cache=False, cache_len=0,
+                pos=pos, shard=shard, decode_combine=decode_combine, layer=i)
+            aux_acc += aux
+        return (x_carry, blocks, i + 1), aux_acc
 
     if prefetch is not None and mode != "train":
         # the step.py contract puts per-device SHARDS in params["blocks"]
@@ -259,7 +286,6 @@ def forward(params, cfg, tokens, *, img_embeds=None, mode="train", cache=None,
         raise NotImplementedError(
             "prefetch pipeline is train-mode only (see DESIGN.md §5)")
     body_fn = jax.checkpoint(body) if (remat and mode == "train") else body
-    scan_cache = cache["blocks"] if decode else None
     if prefetch is not None:
         # Double-buffered pipeline: the scan carries a FIFO of `depth`
         # in-flight gathers. Each iteration issues the gather for layer
@@ -316,9 +342,14 @@ def forward(params, cfg, tokens, *, img_embeds=None, mode="train", cache=None,
                                             length=reps - depth)
             x, aux = drain(x, fifo)
             aux_total += jnp.sum(aux_s) + aux
+    elif decode:
+        (x, scan_ncs, _), aux_s = jax.lax.scan(
+            decode_body, (x, cache["blocks"], jnp.zeros((), jnp.int32)),
+            params["blocks"], length=reps)
+        aux_total += jnp.sum(aux_s)
     else:
-        x, (aux_s, scan_ncs) = jax.lax.scan(
-            body_fn, x, (params["blocks"], scan_cache), length=reps)
+        x, (aux_s, scan_ncs) = jax.lax.scan(body_fn, x, params["blocks"],
+                                            length=reps)
         aux_total += jnp.sum(aux_s)
 
     rest_ncs = []

@@ -88,3 +88,77 @@ def test_zamba2_decode_step_compiles(topo):
     resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert resident < V5E_HBM_BYTES, mem
+
+
+def _dims(text: str) -> tuple[int, ...]:
+    return tuple(int(d) for d in text.split(",") if d)
+
+
+# transformer.forward carries the stacked decode cache through its layer
+# scan and updates it in place. Checked on the chip's compiler (the CPU
+# compiler lowers the per-row token scatter through whole-stack layout
+# copies): no write of a whole (B, L, KV, D) layer of k or v, every cache
+# leaf aliased to the donated input, and no copy of a whole stacked leaf.
+# A head narrower than the 128 lanes gets its KV stack laid out with L
+# minor on the device; the scan works in the other layout, so the entry
+# copies the stack in and out once a step, never inside the scan.
+@pytest.mark.parametrize("arch,head_dim", [("zamba2-1.2b", 128),
+                                           ("zamba2-1.2b", 32),
+                                           ("mamba2-780m", None)])
+def test_decode_step_updates_cache_in_place(one_chip, arch, head_dim):
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from repro import configs
+    from repro.models import transformer
+
+    cfg = configs.get_smoke(arch)
+    if head_dim:
+        cfg = dataclasses.replace(cfg, head_dim=head_dim)
+    B, L = 4, 64
+    _, reps, _ = transformer.find_period(cfg.layer_plan())
+    assert reps >= 2
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                             sharding=one_chip)
+    a_params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda k: transformer.init_params(k, cfg), jax.random.PRNGKey(0)))
+    a_cache = jax.tree.map(on_chip, transformer.cache_specs(
+        cfg, B, L, vector_pos=True))
+    a_tok = on_chip(jax.ShapeDtypeStruct((B, 1), jnp.int32))
+
+    def decode(params, cache, tokens):
+        logits, _, cache = transformer.forward(params, cfg, tokens,
+                                               cache=cache)
+        return logits, cache
+
+    hlo = jax.jit(decode, donate_argnums=(1,)).lower(
+        a_params, a_cache, a_tok).compile().as_text()
+
+    stacked = {s.shape for s in jax.tree.leaves(a_cache["blocks"])}
+    kv_layer = {s.shape[1:] for slot in a_cache["blocks"].values()
+                for name, s in slot.items() if name in ("k", "v")}
+    if arch == "zamba2-1.2b":        # a shared-attention slot in the scan
+        assert kv_layer == {(B, L, cfg.n_kv_heads, cfg.head_dim_)}
+    e0 = hlo.index("\nENTRY ")
+    entry = range(e0, hlo.index("\n}", e0))
+    narrow = head_dim is not None and head_dim % 128 != 0
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* copy\(", hlo):
+        if _dims(m.group(1)) in stacked:
+            assert narrow and m.start() in entry, m.group(0)
+    shapes = {m.group(1): _dims(m.group(2)) for m in re.finditer(
+        r"(%[\w.\-]+) = \w+\[([\d,]*)\]", hlo)}
+    for m in re.finditer(r"dynamic-update-slice\(%[\w.\-]+, (%[\w.\-]+)",
+                         hlo):
+        upd = shapes[m.group(1)]
+        while upd and upd[0] == 1:
+            upd = upd[1:]
+        assert upd not in kv_layer, m.group(0)
+
+    n_params = len(jax.tree.leaves(a_params))
+    n_cache = len(jax.tree.leaves(a_cache))
+    header = hlo.splitlines()[0]
+    aliased = {int(p) for p in re.findall(r"\{[\d,]*\}: \((\d+), \{",
+                                          header)}
+    assert set(range(n_params, n_params + n_cache)) <= aliased, header
