@@ -10,11 +10,15 @@ own under the benchmark's directory:
   cells (``decode_step_ms.chat``) falls back to the reader of its base
   name (``metrics/decode_step_ms.py``) where it has none of its own;
 * limits        — ``limits/<workload>.json``, the numbers ``correct``
-  compares against.
+  compares against;
+* model family  — ``families/<family>.py`` for the configuration's
+  ``family``: its weight layout, plain reference, FLOP counts and test-size
+  cut (``family`` lists what such a module provides).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -68,6 +72,37 @@ def _module(path: Path, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def family(spec: dict):
+    """The module of ``families/<family>.py`` for a configuration file.
+
+    It holds all that is particular to the family's models, and provides:
+
+    * ``shapes(spec, embed_rows)``: the tree of leaf shapes, in the
+      program's layout (``weights.make`` fills it); optionally ``LEAVES``,
+      initialisers for leaf names of its own;
+    * ``hidden(params, tokens, spec, mode)`` and ``logits_at(params, x,
+      rows, cols, spec, mode)``: the plain float32 reference (``mode``
+      "fp8" is the control), output head included;
+    * ``prefill_flops(spec, S)``, ``decode_flops(spec, pos)`` and
+      ``weight_bytes(spec, itemsize)``: the model's operations and bytes;
+    * ``smoke(config)``: the family's own cut to test size, applied after
+      the common one (``tests/smoke.py``).
+    """
+    return _family(spec["family"])
+
+
+@functools.lru_cache(maxsize=None)
+def _family(name: str):
+    # one module object per family, so the reference's jitted functions
+    # are the same objects, and compile once, across calls
+    path = BENCH / "families" / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no module for model family {name!r}: {path} "
+                       "does not exist")
+    return _module(path, "bench_family_" + name.replace("-", "_")
+                   .replace(".", "_"))
 
 
 def driver(cell: Cell):

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.reference import model as ref
+from bench import cells
 
 GAP = "max_logit_gap"
 CHUNK = 512            # positions whose logits are held at once
@@ -53,18 +53,19 @@ def gaps(params, spec: dict, reqs: list, width: int, *,
          control: bool = False) -> np.ndarray:
     """Per served position: the reference's best logit minus the logit of
     the served token, or with ``control`` of the token that the fp8
-    control puts first."""
+    control puts first. The reference is the configuration's family's."""
     import jax.numpy as jnp
+    fam = cells.family(spec)
     toks, rows, cols, served = _batch(reqs, width)
-    x = ref.hidden(params, jnp.asarray(toks), spec, "f32")
-    xc = ref.hidden(params, jnp.asarray(toks), spec, "fp8") if control \
+    x = fam.hidden(params, jnp.asarray(toks), spec, "f32")
+    xc = fam.hidden(params, jnp.asarray(toks), spec, "fp8") if control \
         else None
     out = []
     for a in range(0, len(rows), CHUNK):
         sl = slice(a, a + CHUNK)
-        lg = np.asarray(ref.logits_at(params, x, rows[sl], cols[sl], spec))
+        lg = np.asarray(fam.logits_at(params, x, rows[sl], cols[sl], spec))
         if control:
-            pick = np.asarray(ref.logits_at(params, xc, rows[sl], cols[sl],
+            pick = np.asarray(fam.logits_at(params, xc, rows[sl], cols[sl],
                                             spec, "fp8")).argmax(-1)
         else:
             pick = served[sl]

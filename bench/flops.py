@@ -1,4 +1,6 @@
-"""Model operations and bytes, from a configuration file's shapes.
+"""Model operations and bytes, from a configuration file's shapes: the
+peak table, and the counts of each kind of layer, which each family
+(``bench/families/<family>.py``) sums over its layers.
 
 These are the operations the model requires, not what an implementation
 happens to execute: a matmul of an (m, k) by a (k, n) counts 2mkn, causal
@@ -27,31 +29,37 @@ def peak(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _counts(spec: dict) -> tuple[int, int]:
-    """(Mamba2 layers, shared-block applications)."""
-    order = W.layer_order(spec)
-    return (sum(k != "shared" for k, _ in order),
-            sum(k == "shared" for k, _ in order))
-
-
-def mamba_matmul_params(spec: dict) -> int:
+def mamba_matmul_params(spec: dict, d_inner: int) -> int:
     d = spec["d_model"]
-    di, H, _, N, G = W.ssm_dims(spec)
+    di, H, _, N, G = W.ssm_dims(spec, d_inner)
     return d * (2 * di + 2 * G * N + H) + di * d
 
 
-def shared_matmul_params(spec: dict) -> int:
-    d, D, F = spec["d_model"], spec["head_dim"], spec["d_ff"]
-    H, KV = spec["n_heads"], spec["n_kv_heads"]
-    return 2 * d * H * D + 2 * d * KV * D + 3 * d * F
+def mamba_weight_params(spec: dict, d_inner: int) -> int:
+    """The weights one Mamba2 layer reads: its matmuls and convolution."""
+    di, H, _, N, G = W.ssm_dims(spec, d_inner)
+    return mamba_matmul_params(spec, d_inner) + spec["ssm_conv"] * (
+        di + 2 * G * N)
 
 
-def ssm_flops_per_token(spec: dict) -> int:
+def ssm_flops_per_token(spec: dict, d_inner: int) -> int:
     """One Mamba2 layer's convolution and state update for one token:
     decay and input outer product into the (H, N, P) state (3 per entry),
     its read-out by C (2 per entry), and the depthwise convolution."""
-    di, H, P, N, G = W.ssm_dims(spec)
+    di, H, P, N, G = W.ssm_dims(spec, d_inner)
     return 5 * H * N * P + 2 * spec["ssm_conv"] * (di + 2 * G * N)
+
+
+def attn_matmul_params(spec: dict) -> int:
+    """Query, key, value and output projections of an attention layer."""
+    d, D = spec["d_model"], spec["head_dim"]
+    H, KV = spec["n_heads"], spec["n_kv_heads"]
+    return 2 * d * H * D + 2 * d * KV * D
+
+
+def gated_mlp_params(spec: dict) -> int:
+    """Gate, up and down projections of a gated MLP of width ``d_ff``."""
+    return 3 * spec["d_model"] * spec["d_ff"]
 
 
 def attn_flops(spec: dict, keys: int) -> int:
@@ -59,37 +67,10 @@ def attn_flops(spec: dict, keys: int) -> int:
     return 4 * spec["n_heads"] * spec["head_dim"] * keys
 
 
-def body_flops_per_token(spec: dict) -> int:
-    """Every layer's matmuls and state updates, attention scores aside."""
-    n_mamba, n_shared = _counts(spec)
-    return (n_mamba * (2 * mamba_matmul_params(spec)
-                       + ssm_flops_per_token(spec))
-            + (n_shared * 2 * shared_matmul_params(spec) if n_shared else 0))
+def causal_attn_flops(spec: dict, S: int) -> int:
+    """A prompt of S tokens in one attention layer: query t sees t keys."""
+    return attn_flops(spec, 1) * S * (S + 1) // 2
 
 
 def head_flops(spec: dict) -> int:
     return 2 * spec["vocab_size"] * spec["d_model"]
-
-
-def prefill_flops(spec: dict, S: int) -> int:
-    """A prompt of S tokens, logits for its last position."""
-    _, n_shared = _counts(spec)
-    attn = n_shared and n_shared * attn_flops(spec, 1) * S * (S + 1) // 2
-    return S * body_flops_per_token(spec) + attn + head_flops(spec)
-
-
-def decode_flops(spec: dict, pos: int) -> int:
-    """One decoded token at position ``pos`` (it sees pos + 1 keys)."""
-    _, n_shared = _counts(spec)
-    attn = n_shared and n_shared * attn_flops(spec, pos + 1)
-    return body_flops_per_token(spec) + attn + head_flops(spec)
-
-
-def weight_bytes(spec: dict, itemsize: int = 2) -> int:
-    """Bytes of the weights one decode step reads."""
-    n_mamba, n_shared = _counts(spec)
-    di, H, _, N, G = W.ssm_dims(spec)
-    per_mamba = mamba_matmul_params(spec) + spec["ssm_conv"] * (di + 2 * G * N)
-    shared = shared_matmul_params(spec) if n_shared else 0
-    return itemsize * (n_mamba * per_mamba + shared
-                       + spec["vocab_size"] * spec["d_model"])

@@ -75,20 +75,22 @@ def mean_span_ms(run: Run, name: str) -> float | None:
 
 def serve_mfu(run: Run) -> float | None:
     """Model FLOPs of the prefills and decoded tokens in the window over
-    the time in their spans at the chip's peak, in percent."""
-    from bench import flops
+    the time in their spans at the chip's peak, in percent; the counts are
+    the configuration's family's."""
+    from bench import cells
     spec = run.spec
     prefills = run.window_spans("serve/prefill")
     decodes = run.window_spans("serve/decode_step")
     busy = sum(s["t1"] - s["t0"] for s in prefills + decodes)
     if not busy:
         return None
-    work = sum(flops.prefill_flops(spec, s["args"]["prompt_len"])
+    fam = cells.family(spec)
+    work = sum(fam.prefill_flops(spec, s["args"]["prompt_len"])
                for s in prefills)
     for r in run.requests:
         # token 0 comes from the prefill; token i from the decode step fed
         # token i-1 at position prompt_len + i - 1
         for i, t in enumerate(r.times[1:], start=1):
             if run.in_window(t):
-                work += flops.decode_flops(spec, r.prompt_len + i - 1)
+                work += fam.decode_flops(spec, r.prompt_len + i - 1)
     return 100.0 * work / (busy * run.peak["bf16_flops_per_s"])

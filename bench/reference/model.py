@@ -1,16 +1,18 @@
-"""Plain float32 forward pass of the benchmark's models.
+"""Building blocks of the plain float32 forward passes of the benchmark's
+models; each family (``bench/families/<family>.py``) puts them together.
 
 Straight ``jax.numpy`` with every matmul at ``Precision.HIGHEST``; nothing
-here imports the program. It reads the weight tree that
-:mod:`bench.weights` lays out and runs one layer at a time, so that a
-full-depth model fits beside what is left on the chip:
+here imports the program. :func:`hidden` runs one layer at a time, each
+kind of layer one jitted program, so that a full-depth model fits beside
+what is left on the chip:
 
 * Mamba2 mixer (arXiv:2405.21060) as the per-token recurrence
   ``h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T``, ``y_t = C_t h_t + D x_t``,
   after a causal depthwise convolution, gated as ``norm(y * silu(z))``;
-* the hybrid family's shared block (Zamba2, arXiv:2411.15242, as the
-  configuration file's ``departures`` describe it): pre-norm causal
-  multi-head attention with rotary positions, then a pre-norm GeGLU MLP.
+  its inner width is its weights';
+* causal multi-head attention with grouped key/value heads and rotary
+  positions;
+* the embedding, and an output head tied to it.
 
 ``mode="fp8"`` is the control: the same computation with the inputs of
 every matmul rounded to float8 (e4m3, one scale per tensor), the precision
@@ -62,7 +64,7 @@ def causal_conv(u, w, b):
 
 def mamba_mixer(p, x, spec, mode):
     """x (B,S,d) float32 -> (B,S,d): the Mamba2 mixer, token by token."""
-    di, H, P, N, G = W.ssm_dims(spec)
+    di, H, P, N, G = W.ssm_dims(spec, p["out_proj"].shape[0])
     Bt, S, _ = x.shape
     proj = einsum(mode, "bsd,de->bse", x, p["in_proj"])
     z = proj[..., :di]
@@ -110,12 +112,11 @@ def rope(t, theta):
     return jnp.concatenate([t1 * cos - t2 * sin, t1 * sin + t2 * cos], -1)
 
 
-def shared_block(p, x, spec, mode):
-    """Pre-norm causal attention + pre-norm GeGLU MLP, both residual."""
-    Bt, S, _ = x.shape
+def attention(a, h, spec, mode):
+    """Causal attention of the normed ``h`` (B,S,d) with the weights ``a``
+    (``wq``, ``wk``, ``wv``, ``wo``), back in the residual's width."""
+    Bt, S, _ = h.shape
     H, KV, D = spec["n_heads"], spec["n_kv_heads"], spec["head_dim"]
-    a = p["attn"]
-    h = rmsnorm(x, p["ln1"]["scale"], spec["norm_eps"])
     q = einsum(mode, "bsd,de->bse", h, a["wq"]).reshape(Bt, S, H, D)
     k = einsum(mode, "bsd,de->bse", h, a["wk"]).reshape(Bt, S, KV, D)
     v = einsum(mode, "bsd,de->bse", h, a["wv"]).reshape(Bt, S, KV, D)
@@ -126,29 +127,30 @@ def shared_block(p, x, spec, mode):
     causal = jnp.tril(jnp.ones((S, S), bool))
     s = jnp.where(causal, s, -jnp.inf)
     o = einsum(mode, "bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
-    x = x + einsum(mode, "bse,ed->bsd", o.reshape(Bt, S, H * D), a["wo"])
-    m = p["mlp"]
-    h = rmsnorm(x, p["ln2"]["scale"], spec["norm_eps"])
-    g = jax.nn.gelu(einsum(mode, "bsd,df->bsf", h, m["gate"]),
-                    approximate=False)
-    u = einsum(mode, "bsd,df->bsf", h, m["up"])
-    return x + einsum(mode, "bsf,fd->bsd", g * u, m["down"])
+    return einsum(mode, "bse,ed->bsd", o.reshape(Bt, S, H * D), a["wo"])
+
+
+def embed(e, t, spec, mode):
+    return e[t].astype(jnp.float32)
+
+
+def tied_head(e, n, x, spec, mode):
+    """Logits of the hidden states ``x`` (n, d) through the final norm and
+    the embedding's first ``vocab_size`` rows."""
+    V = spec["vocab_size"]
+    return einsum(mode, "bd,vd->bv", rmsnorm(x, n, spec["norm_eps"]), e[:V])
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled(kind: str, spec_items: tuple, mode: str):
+def _compiled(fn, spec_items: tuple, mode: str):
     spec = dict(spec_items)
-    if kind == "mamba":
-        return jax.jit(lambda p, x: mamba_layer(p, x, spec, mode))
-    if kind == "shared":
-        return jax.jit(lambda p, x: shared_block(p, x, spec, mode))
-    if kind == "embed":
-        return jax.jit(lambda e, t: e[t].astype(jnp.float32))
-    if kind == "head":
-        V = spec["vocab_size"]
-        return jax.jit(lambda e, n, x: einsum(
-            mode, "bd,vd->bv", rmsnorm(x, n, spec["norm_eps"]), e[:V]))
-    raise ValueError(kind)
+    return jax.jit(lambda *args: fn(*args, spec, mode))
+
+
+def jitted(fn, spec: dict, mode: str):
+    """``fn(*arrays, spec, mode)`` as one jitted program of the arrays,
+    built once for each function, configuration and mode."""
+    return _compiled(fn, _key(spec), mode)
 
 
 def _key(spec: dict) -> tuple:
@@ -156,23 +158,22 @@ def _key(spec: dict) -> tuple:
                         if isinstance(v, (int, float, str, bool))))
 
 
-def hidden(params, tokens, spec: dict, mode: str = "f32"):
-    """Final hidden states (B,S,d) float32 of right-padded ``tokens``."""
-    key = _key(spec)
+def hidden(embedding, tokens, layers, spec: dict, mode: str):
+    """Final hidden states (B,S,d) float32 of right-padded ``tokens``:
+    the embedding, then each ``(fn, weights)`` of ``layers`` in order as
+    ``x = fn(weights, x, spec, mode)``. A family passes ``layers`` as a
+    generator, so that only one layer's weights are picked out at a time."""
     with jax.default_matmul_precision("highest"):
-        x = _compiled("embed", key, mode)(params["embed"], tokens)
-        for kind, where in W.layer_order(spec):
-            if kind == "shared":
-                x = _compiled("shared", key, mode)(params["shared_attn"], x)
-            else:
-                x = _compiled("mamba", key, mode)(
-                    W.mamba_layer(params, (kind, where)), x)
+        x = jitted(embed, spec, mode)(embedding, tokens)
+        for fn, weights in layers:
+            x = jitted(fn, spec, mode)(weights, x)
     return x
 
 
-def logits_at(params, x, rows, cols, spec: dict, mode: str = "f32"):
-    """Logits (n, vocab) float32 at positions ``x[rows, cols]``."""
+def tied_logits_at(params, x, rows, cols, spec: dict, mode: str = "f32"):
+    """Logits (n, vocab) float32 at positions ``x[rows, cols]``, through
+    the output head tied to ``embed``."""
     with jax.default_matmul_precision("highest"):
         sel = x[jnp.asarray(rows), jnp.asarray(cols)]
-        return _compiled("head", _key(spec), mode)(
+        return jitted(tied_head, spec, mode)(
             params["embed"], params["final_norm"]["scale"], sel)

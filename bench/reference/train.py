@@ -1,22 +1,24 @@
 """Plain float32 loss and gradients: next-token cross-entropy over the
-reference forward pass (:mod:`bench.reference.model`), averaged over every
-position, with the softmax over the configuration's own vocabulary."""
+reference forward pass of the configuration's family
+(``bench/families/<family>.py``), averaged over every position, with the
+softmax over the configuration's own vocabulary."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from bench.reference import model as ref
+from bench import cells
 
 
 def loss(params, tokens, labels, spec: dict, mode: str = "f32"):
-    x = ref.hidden(params, tokens, spec, mode)
-    h = ref.rmsnorm(x, params["final_norm"]["scale"], spec["norm_eps"])
-    emb = params["embed"][:spec["vocab_size"]]
-    with jax.default_matmul_precision("highest"):
-        logits = ref.einsum(mode, "bsd,vd->bsv", h, emb)
+    fam = cells.family(spec)
+    x = fam.hidden(params, tokens, spec, mode)
+    B, S = tokens.shape
+    logits = fam.logits_at(params, x, np.repeat(np.arange(B), S),
+                           np.tile(np.arange(S), B), spec, mode)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    picked = jnp.take_along_axis(logits, labels.reshape(-1, 1), axis=-1)[:, 0]
     return jnp.mean(lse - picked)
 
 

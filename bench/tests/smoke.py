@@ -22,10 +22,8 @@ TRAFFIC = {"prompt": {"median": 24, "sigma": 0.8, "min": 16, "max": 64,
 
 def smoke_cell(name: str, **traffic) -> cells.Cell:
     cell = cells.load_cell(name)
-    config = dict(cell.config, **{k: v for k, v in SIZES.items()
-                                  if k in cell.config})
-    if config["family"] == "hybrid":
-        config["shared_attn_every"] = 2
+    config = cells.family(cell.config).smoke(dict(
+        cell.config, **{k: v for k, v in SIZES.items() if k in cell.config}))
     mix = dict(cell.traffic, **dict(TRAFFIC, **traffic))
     if mix["arrivals"]["process"] == "poisson":
         mix["arrivals"] = {"process": "poisson", "rate_per_s": 20.0}

@@ -10,17 +10,24 @@ the tanh form of GELU where the reference takes the exact one.
 """
 from __future__ import annotations
 
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import weights as W
+from bench import cells, weights as W
 from bench.drivers.serve import program_config
-from bench.reference import model as ref, train as ref_train
+from bench.reference import train as ref_train
 from bench.tests.smoke import smoke_cell
 
-CELLS = ("zamba2-1.2b.chat", "mamba2-780m.offline")
+# every cell of the benchmark, so that a configuration added by files gets
+# these tests with no edit
+CELLS = tuple(w["name"] for w in json.loads(
+    (Path(__file__).resolve().parents[2] / "BENCHMARK.json").read_text()
+)["workloads"])
 # max |program - reference| / max |reference|. The SSD's bf16 products
 # (2^-8 relative) measured 2.7e-3 over 4-6 layers; an equation wrong in
 # one place (conv offset, gate, decay sign) reads O(1).
@@ -54,10 +61,9 @@ def test_weight_tree_is_the_programs(name):
 @pytest.mark.parametrize("name", CELLS)
 def test_published_sizes_give_the_programs_parameter_count(name):
     """Full size, by arithmetic only: nothing is traced or allocated."""
-    from bench import cells
     spec = cells.load_cell(name).config
     cfg = program_config(spec)
-    tree = W.shapes(spec, cfg.padded_vocab)
+    tree = cells.family(spec).shapes(spec, cfg.padded_vocab)
     n = sum(int(np.prod(s)) for s in jax.tree.leaves(
         tree, is_leaf=lambda s: isinstance(s, tuple)))
     assert n == cfg.param_count()
@@ -91,8 +97,9 @@ def test_prefill_then_decode_matches_reference(name, wide_heads):
             params, cfg, jnp.asarray(toks[None, P + t:P + t + 1]),
             cache=cache)
         got.append(logits[0, -1, :spec["vocab_size"]])
-    x = ref.hidden(params, jnp.asarray(toks[None]), spec)
-    want = ref.logits_at(params, x, [0] * steps, list(range(P - 1,
+    fam = cells.family(spec)
+    x = fam.hidden(params, jnp.asarray(toks[None]), spec)
+    want = fam.logits_at(params, x, [0] * steps, list(range(P - 1,
                                                            P - 1 + steps)),
                          spec)
     assert _rel(jnp.stack(got), want) < TOL
@@ -126,6 +133,7 @@ def test_fp8_control_departs_from_reference():
     spec, cfg, params = _setup("zamba2-1.2b.chat")
     toks = jnp.asarray(np.random.default_rng(5).integers(
         0, spec["vocab_size"], (1, 32), dtype=np.int32))
-    a = ref.hidden(params, toks, spec)
-    b = ref.hidden(params, toks, spec, "fp8")
+    fam = cells.family(spec)
+    a = fam.hidden(params, toks, spec)
+    b = fam.hidden(params, toks, spec, "fp8")
     assert _rel(b, a) > 1e-2

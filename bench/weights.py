@@ -5,13 +5,15 @@ in (bf16), from ``--seed``. The same tree feeds the program under test and
 the plain reference, so the two run on identical numbers; nothing here
 imports the program.
 
-Layout (what ``repro.models.transformer`` consumes): ``embed`` (rows, d)
-tied to the output head; ``final_norm``; for the hybrid family one
-``shared_attn`` block; ``blocks`` holding one stacked slot per position in
-the layer period (the shared block's slot is empty, its weights live once
-at the top); ``rest`` the Mamba2 layers after the last whole period.
-Norm weights are stored as ``scale`` with the norm multiplying by
-``1 + scale``.
+The tree of leaf shapes is each family's (``shapes`` of
+``families/<family>.py``, found by ``cells.family``): ``embed`` (rows, d),
+``final_norm``, ``blocks`` holding one stacked slot per position in the
+layer period, ``rest`` the layers after the last whole period, and what
+else the family holds. Here are the rules that make a leaf's values from
+its name, which a family may extend with a ``LEAVES`` dict of its own
+(name -> ``fn(key, name, shape, spec, dtype)``), and the Mamba2 layer's
+shapes, which several families use. Norm weights are stored as ``scale``
+with the norm multiplying by ``1 + scale``.
 """
 from __future__ import annotations
 
@@ -24,47 +26,17 @@ import numpy as np
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
 
-def ssm_dims(spec: dict) -> tuple[int, int, int, int, int]:
-    """(d_inner, heads, head size, state size, groups) of a Mamba2 layer."""
-    d_inner = spec["ssm_expand"] * spec["d_model"]
+def ssm_dims(spec: dict, d_inner: int) -> tuple[int, int, int, int, int]:
+    """(d_inner, heads, head size, state size, groups) of a Mamba2 layer
+    whose inner width the family gives."""
     P = spec["ssm_headdim"]
     return d_inner, d_inner // P, P, spec["ssm_state"], spec["ssm_ngroups"]
 
 
-def period(spec: dict) -> tuple[int, int, int]:
-    """(layers per period, whole periods, Mamba2 layers left over).
-
-    A hybrid model applies the shared block after every
-    ``shared_attn_every`` Mamba2 layers, so its period is that many Mamba2
-    layers plus the block; an SSM model's period is one layer."""
-    n = spec["n_layers"]
-    if spec["family"] == "hybrid":
-        k = spec["shared_attn_every"]
-        return k + 1, n // k, n % k
-    if spec["family"] == "ssm":
-        return 1, n, 0
-    raise ValueError(f"no weight layout for family {spec['family']!r}")
-
-
-def layer_order(spec: dict) -> list[tuple[str, object]]:
-    """The model's layers in order: ("mamba", (slot, rep)) for a stacked
-    Mamba2 layer, ("rest", i) for a leftover one, ("shared", None) for an
-    application of the shared block."""
-    pi, reps, rem = period(spec)
-    out: list[tuple[str, object]] = []
-    for r in range(reps):
-        for j in range(pi):
-            if spec["family"] == "hybrid" and j == pi - 1:
-                out.append(("shared", None))
-            else:
-                out.append(("mamba", (j, r)))
-    out += [("rest", i) for i in range(rem)]
-    return out
-
-
-def _mamba_shapes(spec: dict) -> dict:
+def mamba_shapes(spec: dict, d_inner: int) -> dict:
+    """One pre-norm Mamba2 layer: ``ln`` and the ``mamba`` mixer."""
     d = spec["d_model"]
-    di, H, _, N, G = ssm_dims(spec)
+    di, H, _, N, G = ssm_dims(spec, d_inner)
     conv_ch = di + 2 * G * N
     return {"ln": {"scale": (d,)},
             "mamba": {"in_proj": (d, 2 * di + 2 * G * N + H),
@@ -74,32 +46,10 @@ def _mamba_shapes(spec: dict) -> dict:
                       "out_proj": (di, d)}}
 
 
-def _shared_shapes(spec: dict) -> dict:
-    d, D = spec["d_model"], spec["head_dim"]
-    H, KV, F = spec["n_heads"], spec["n_kv_heads"], spec["d_ff"]
-    return {"ln1": {"scale": (d,)},
-            "attn": {"wq": (d, H * D), "wk": (d, KV * D), "wv": (d, KV * D),
-                     "wo": (H * D, d)},
-            "ln2": {"scale": (d,)},
-            "mlp": {"gate": (d, F), "up": (d, F), "down": (F, d)}}
-
-
-def shapes(spec: dict, embed_rows: int) -> dict:
-    """The tree of leaf shapes."""
-    pi, reps, rem = period(spec)
-    stack = lambda tree: jax.tree.map(lambda s: (reps,) + s, tree,
-                                      is_leaf=lambda s: isinstance(s, tuple))
-    blocks = {}
-    for j in range(pi):
-        shared_slot = spec["family"] == "hybrid" and j == pi - 1
-        blocks[f"slot{j}"] = {} if shared_slot else stack(_mamba_shapes(spec))
-    tree = {"embed": (embed_rows, spec["d_model"]),
-            "final_norm": {"scale": (spec["d_model"],)},
-            "blocks": blocks,
-            "rest": [_mamba_shapes(spec) for _ in range(rem)]}
-    if spec["family"] == "hybrid":
-        tree["shared_attn"] = _shared_shapes(spec)
-    return tree
+def stacked(tree: dict, reps: int) -> dict:
+    """``tree``'s shapes with a leading axis of ``reps`` layers."""
+    return jax.tree.map(lambda s: (reps,) + s, tree,
+                        is_leaf=lambda s: isinstance(s, tuple))
 
 
 def _leaf(key, name: str, shape: tuple, spec: dict, dtype):
@@ -140,23 +90,28 @@ def seed_key(seed: int) -> jax.Array:
 
 def make(spec: dict, seed: int, embed_rows: int, *, sharding=None) -> dict:
     """Every weight, made on the device in one jitted call."""
+    from bench import cells
+    fam = cells.family(spec)
+    own = getattr(fam, "LEAVES", {})
     dtype = DTYPES[spec["weights_dtype"]]
-    tree = shapes(spec, embed_rows)
+    tree = fam.shapes(spec, embed_rows)
     paths, treedef = jax.tree_util.tree_flatten_with_path(
         tree, is_leaf=lambda s: isinstance(s, tuple))
 
     def build(key):
         keys = jax.random.split(key, len(paths))
-        leaves = [_leaf(k, str(path[-1].key), shp, spec, dtype)
-                  for k, (path, shp) in zip(keys, paths)]
+        names = [str(path[-1].key) for path, _ in paths]
+        leaves = [own.get(name, _leaf)(k, name, shp, spec, dtype)
+                  for k, name, (_, shp) in zip(keys, names, paths)]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     fn = jax.jit(build, out_shardings=sharding)
     return fn(seed_key(seed))
 
 
-def mamba_layer(params: dict, where) -> dict:
-    """One Mamba2 layer's weights, picked out of the stacked tree."""
+def layer_weights(params: dict, where) -> dict:
+    """One layer's weights: ("rest", i) for a layer after the last whole
+    period, else (kind, (slot, rep)) picked out of the stacked tree."""
     kind, at = where
     if kind == "rest":
         return params["rest"][at]
